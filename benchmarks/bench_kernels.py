@@ -64,4 +64,6 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compilation_cache
+    enable_compilation_cache()
     run()

@@ -33,4 +33,6 @@ def run(settings=((6, 4), (14, 4), (3, 8), (1, 1)),
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compilation_cache
+    enable_compilation_cache()
     run()

@@ -43,4 +43,6 @@ def run(dataset="SYN10K", settings=((3, 8), (6, 4), (8, 4), (14, 4), (16, 3),
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compilation_cache
+    enable_compilation_cache()
     run()

@@ -190,6 +190,8 @@ def run_mesh(target_slots: int = 1_200_000,
 
 
 if __name__ == "__main__":  # PYTHONPATH=src python -m benchmarks.bench_pairs
+    from repro.runtime import enable_compilation_cache
+    enable_compilation_cache()
     import argparse
     import os
     import sys
@@ -210,12 +212,16 @@ if __name__ == "__main__":  # PYTHONPATH=src python -m benchmarks.bench_pairs
                     help="write the BENCH_pairs.json perf record")
     args = ap.parse_args()
     if args.mesh:
+        if os.environ.get("JAX_PLATFORMS") != "cpu":
+            # the 8 "hosts" are emulated CPU devices; on an accelerator
+            # this would silently time whatever chips exist under that name
+            sys.exit("--mesh emulates 8 hosts on the CPU backend; run it "
+                     "with JAX_PLATFORMS=cpu")
         if "--xla_force_host_platform_device_count" not in os.environ.get(
                 "XLA_FLAGS", ""):
             env = dict(os.environ)
             env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                                 + " --xla_force_host_platform_device_count=8").strip()
-            env.pop("JAX_PLATFORMS", None)
             os.execve(sys.executable,
                       [sys.executable, "-m", "benchmarks.bench_pairs"]
                       + sys.argv[1:], env)

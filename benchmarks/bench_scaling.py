@@ -37,4 +37,6 @@ def run(datasets=("SYN10K", "SYN30K", "SYN100K", "SYN300K"),
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compilation_cache
+    enable_compilation_cache()
     run()

@@ -101,6 +101,8 @@ def run(n_records: int = 50_000, n_probes: int = 2_048,
 
 
 if __name__ == "__main__":  # PYTHONPATH=src python -m benchmarks.bench_serving
+    from repro.runtime import enable_compilation_cache
+    enable_compilation_cache()
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)

@@ -178,6 +178,8 @@ def run(check_speedup: bool = False, n_records: int = 100_000,
 
 
 if __name__ == "__main__":  # PYTHONPATH=src python -m benchmarks.bench_streaming
+    from repro.runtime import enable_compilation_cache
+    enable_compilation_cache()
     import argparse
 
     ap = argparse.ArgumentParser(description=__doc__)

@@ -44,4 +44,6 @@ def run(datasets=("SYN10K", "VOTERSYN", "SYN100K"), max_block_size=200):
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compilation_cache
+    enable_compilation_cache()
     run()

@@ -45,4 +45,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compilation_cache
+    enable_compilation_cache()
     main()

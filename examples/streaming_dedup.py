@@ -84,4 +84,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compilation_cache
+    enable_compilation_cache()
     main()

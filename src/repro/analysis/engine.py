@@ -140,7 +140,7 @@ class ModuleContext:
         self.partial_names: Set[str] = set()     # from functools import partial
         self.cache_deco_names: Set[str] = set()  # lru_cache / cache
         self.perf_counter_names: Set[str] = set()
-        self.shard_map_names: Set[str] = set()   # from jax.experimental.shard_map import shard_map
+        self.shard_map_names: Set[str] = set()   # from jax import shard_map
         self.pallas_call_names: Set[str] = set()
         self.imports_jaxlike = False             # jax / jnp / repro imported
 
@@ -296,7 +296,8 @@ class ModuleContext:
         if any(d == f"{a}.pallas_call" for a in self.pallas_aliases):
             return True
         return any(
-            d in (f"{a}.vmap", f"{a}.experimental.shard_map.shard_map")
+            d in (f"{a}.vmap", f"{a}.shard_map",
+                  f"{a}.experimental.shard_map.shard_map")
             for a in self.jax_aliases
         )
 

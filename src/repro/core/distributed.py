@@ -60,13 +60,13 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import hashing, routing, segments, sketches, u64
 from ..distributed import sharding
 from .hdb import (BlockingResult, HDBConfig, INT32_MAX, IterationStats,
-                  RepCapacityWarning, intersect_keys)
+                  RepCapacityWarning, intersect_keys,
+                  pad_to_intersect_width)
 from .routing import route_buckets as _route
 
 logger = logging.getLogger(__name__)
@@ -205,6 +205,8 @@ def _make_hdb_step_cached(cfg: HDBConfig, mesh: Mesh,
         # ---- intersect locally (Alg. 2) ----
         new_key, new_valid, new_psize, n_dropped_mk = intersect_keys(
             cfg, key, survive, ex_size)
+        new_key, new_valid, new_psize = pad_to_intersect_width(
+            cfg, new_key, new_valid, new_psize)
 
         def tot(x):
             return jax.lax.psum(jnp.sum(x.astype(jnp.int32)), axes)
@@ -231,11 +233,11 @@ def _make_hdb_step_cached(cfg: HDBConfig, mesh: Mesh,
         "n_live_keys", "n_right_cms", "n_right_exact", "n_dropped_similarity",
         "n_dropped_max_keys", "n_duplicate_blocks", "n_surviving_oversized",
         "n_surviving_entries", "rep_overflow"]}
-    mapped = shard_map(
+    mapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(spec3, spec2, spec2),
         out_specs=(spec2, spec3, spec2, spec2, stats_spec),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(mapped)
 
 
@@ -328,7 +330,7 @@ def _pair_contract_reason(blocks, budget: int, per_round: int,
 
 @functools.lru_cache(maxsize=64)
 def _make_routed_round_step(mesh, axes, n_shards: int, chunk: int, cap: int,
-                            steps: int, interpret: bool, sampled: bool):
+                            steps: int, sampled: bool):
     """Build the jitted shard_mapped decode+pack+route+exchange round.
 
     Exact mode decodes slots [base, base+chunk) per shard (``total`` is a
@@ -353,7 +355,7 @@ def _make_routed_round_step(mesh, axes, n_shards: int, chunk: int, cap: int,
         def local_round(start, size, members, block, local, valid):
             a, b, s, v = pairs_kernels.decode_block_local(
                 start, size, members, block[0], local[0], valid[0],
-                steps=steps, use_kernel=False, interpret=interpret)
+                steps=steps, use_kernel=False)
             return shared_tail(a, b, s, v)
 
         in_specs = (P(), P(), P(), P(axes, None), P(axes, None),
@@ -362,21 +364,20 @@ def _make_routed_round_step(mesh, axes, n_shards: int, chunk: int, cap: int,
         def local_round(cum, start, size, members, base, total):
             a, b, s, v = pairs_kernels.decode_chunk(
                 cum, start, size, members, base[0], total,
-                chunk=chunk, steps=steps, use_kernel=False,
-                interpret=interpret)
+                chunk=chunk, steps=steps, use_kernel=False)
             return shared_tail(a, b, s, v)
 
         in_specs = (P(), P(), P(), P(), P(axes), P())
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_round, mesh=mesh, in_specs=in_specs,
-        out_specs=(P(axes), P(axes), P()), check_rep=False))
+        out_specs=(P(axes), P(axes), P()), check_vma=False))
 
 
 @functools.lru_cache(maxsize=64)
 def _make_local_dedupe(mesh, axes, n_rounds: int,
                        sort_backend: str = "comparator",
-                       n_passes: int = 16, interpret: bool = True):
+                       n_passes: int = 16):
     """Build the shard-local sort-dedupe over the accumulated buckets.
 
     ``sort_backend`` picks the in-shard sort engine (comparator
@@ -390,35 +391,35 @@ def _make_local_dedupe(mesh, axes, n_rounds: int,
         lo = jnp.concatenate(bufs[n_rounds:])
         return pairs_kernels.dedupe_packed_device(
             hi, lo, sort_backend=sort_backend, n_passes=n_passes,
-            use_kernel=False, interpret=interpret)
+            use_kernel=False)
 
     specs = (P(axes),) * (2 * n_rounds)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_dedupe, mesh=mesh, in_specs=specs,
-        out_specs=(P(axes), P(axes), P(axes)), check_rep=False))
+        out_specs=(P(axes), P(axes), P(axes)), check_vma=False))
 
 
 @functools.lru_cache(maxsize=64)
-def _make_decode_round_step(mesh, axes, chunk: int, interpret: bool):
+def _make_decode_round_step(mesh, axes, chunk: int):
     """Decode-only round of the legacy global-sort path (cached jit)."""
     from ..kernels import pairs as pairs_kernels
 
     def local_decode(cum, start, size, members, base, total):
         return pairs_kernels.decode_chunk(
             cum, start, size, members, base[0], total,
-            chunk=chunk, use_kernel=False, interpret=interpret)
+            chunk=chunk, use_kernel=False)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_decode, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(axes), P()),
         out_specs=(P(axes), P(axes), P(axes), P(axes)),
-        check_rep=False))
+        check_vma=False))
 
 
 def dedupe_pairs_distributed(
     blocks, mesh: Mesh, axis_names: Sequence[str] = ("data",),
     budget: int = 50_000_000, chunk_per_shard: int = 1 << 18,
-    route_slack: float = 2.0, interpret: bool = True, sample_seed: int = 0,
+    route_slack: float = 2.0, sample_seed: int = 0,
     sort_backend: str = "auto",
 ):
     """Fingerprint-routed distributed pair dedupe (no global sort).
@@ -485,7 +486,6 @@ def dedupe_pairs_distributed(
                           stacklevel=2)
         return pairs_lib.dedupe_pairs(blocks, budget=budget,
                                       sample_seed=sample_seed,
-                                      interpret=interpret,
                                       sort_backend=sort_backend)
 
     # host casts + explicit uploads: dtype-coercing jnp.asarray and scalar
@@ -497,7 +497,7 @@ def dedupe_pairs_distributed(
     steps = pairs_kernels.search_steps_for(int(blocks.size.max()))
     cap = int(np.ceil(chunk / n_shards * route_slack))
     step = _make_routed_round_step(mesh, axes, n_shards, chunk, cap,
-                                   steps, interpret, sampled=not exact)
+                                   steps, sampled=not exact)
 
     rhi, rlo, ovfs = [], [], []
     if exact:
@@ -538,13 +538,12 @@ def dedupe_pairs_distributed(
             RepCapacityWarning, stacklevel=2)
         return pairs_lib.dedupe_pairs(blocks, budget=budget,
                                       sample_seed=sample_seed,
-                                      interpret=interpret,
                                       sort_backend=sort_backend)
 
     # routed pairs always satisfy the pack bound (contract check above),
     # so "auto" resolves to the per-platform winner and "radix" never
     # degrades here
-    sort_kind = pairs_lib._resolve_sort_backend(sort_backend, blocks)
+    sort_kind = pairs_lib.resolve_sort_backend(sort_backend, blocks)
     if sort_kind == "host":
         # CPU mirror of the single-device driver's packed strategy: each
         # shard's routed bucket is sorted with numpy's u64 sort (host ==
@@ -564,7 +563,7 @@ def dedupe_pairs_distributed(
         n_passes = (pairs_lib._radix_passes_for_blocks(blocks)
                     if sort_kind == "radix" else 16)
         dedupe = _make_local_dedupe(mesh, axes, len(rhi), sort_kind,
-                                    n_passes, interpret)
+                                    n_passes)
         shi, slo, winner = dedupe(*rhi, *rlo)
         w = np.asarray(winner)
         words = ((np.asarray(shi).astype(np.uint64) << np.uint64(32))
@@ -579,7 +578,7 @@ def dedupe_pairs_distributed(
 def materialize_pairs_distributed(
     blocks, mesh: Mesh, axis_names: Sequence[str] = ("data",),
     budget: int = 50_000_000, chunk_per_shard: int = 1 << 18,
-    interpret: bool = True, sample_seed: int = 0,
+    sample_seed: int = 0,
     dedupe: str = "routed", route_slack: float = 2.0,
     sort_backend: str = "auto",
 ):
@@ -599,8 +598,7 @@ def materialize_pairs_distributed(
         return dedupe_pairs_distributed(
             blocks, mesh, axis_names, budget=budget,
             chunk_per_shard=chunk_per_shard, route_slack=route_slack,
-            interpret=interpret, sample_seed=sample_seed,
-            sort_backend=sort_backend)
+            sample_seed=sample_seed, sort_backend=sort_backend)
     if dedupe != "global":
         raise ValueError(f"dedupe must be 'routed' or 'global', got {dedupe!r}")
 
@@ -624,7 +622,6 @@ def materialize_pairs_distributed(
                           stacklevel=2)
         return pairs_lib.dedupe_pairs(blocks, budget=budget,
                                       sample_seed=sample_seed,
-                                      interpret=interpret,
                                       sort_backend=sort_backend)
 
     cum32 = jnp.asarray(pairs_ref.cum_pair_counts(blocks.size).astype(np.int32))
@@ -632,7 +629,7 @@ def materialize_pairs_distributed(
     size32 = jnp.asarray(blocks.size.astype(np.int32))
     mem32 = jnp.asarray(blocks.members.astype(np.int32))
     total32 = jax.device_put(np.int32(total))
-    mapped = _make_decode_round_step(mesh, axes, chunk, interpret)
+    mapped = _make_decode_round_step(mesh, axes, chunk)
 
     shard_offsets = np.arange(n_shards, dtype=np.int32) * chunk
     out_a, out_b, out_s, out_v = [], [], [], []
@@ -644,7 +641,7 @@ def materialize_pairs_distributed(
     # the legacy baseline is "one big device sort": "host" (a CPU-only
     # shortcut of the routed/single-device drivers) maps to the
     # comparator here so the baseline stays a device sort measurement
-    sort_kind = pairs_lib._resolve_sort_backend(sort_backend, blocks)
+    sort_kind = pairs_lib.resolve_sort_backend(sort_backend, blocks)
     if sort_kind == "host":
         sort_kind = "comparator"
     kw = {}
@@ -653,7 +650,7 @@ def materialize_pairs_distributed(
     sa, sb, ss, winner = pairs_kernels.dedupe_device(
         jnp.asarray(np.concatenate(out_a)), jnp.asarray(np.concatenate(out_b)),
         jnp.asarray(np.concatenate(out_s)), jnp.asarray(np.concatenate(out_v)),
-        sort_backend=sort_kind, use_kernel=False, interpret=interpret, **kw)
+        sort_backend=sort_kind, use_kernel=False, **kw)
     w = np.asarray(winner)
     return pairs_lib.PairSet(
         a=np.asarray(sa)[w].astype(np.int64),

@@ -1,8 +1,11 @@
 """Hashed Dynamic Blocking — Algorithms 1–4 of the paper, in fixed-shape JAX.
 
 The iteration state is a dense per-record key matrix (records never move;
-only 64-bit key hashes flow — the paper's data-movement thesis). Each host-
-level iteration runs one jit-compiled step:
+only 64-bit key hashes flow — the paper's data-movement thesis). From the
+second iteration on it is padded to one width, ``intersect_width``, so each
+jitted step compiles for two widths per run. Each host-level iteration runs
+two jit-compiled steps around a host dedupe of the over-sized block
+representatives:
 
   1. ROUGH OVER-SIZE DETECTION (Alg. 3): build a Count-Min Sketch over all
      live (record, key) entries, query approximate block sizes. Keys with
@@ -13,7 +16,8 @@ level iteration runs one jit-compiled step:
      segmented count + XOR-of-rid-fingerprints give every entry its exact
      block size and its block's membership hash. Blocks the CMS over-counted
      are recovered as right-sized. Over-sized blocks with identical
-     membership hashes are duplicates — one survivor is kept (smallest key).
+     membership hashes are duplicates — one survivor is kept (smallest key);
+     that dedupe runs on the host over one representative per block.
   3. INTERSECT KEYS (Alg. 2): each record combines pairs of its surviving
      over-sized keys into new candidate keys carrying
      ``psize = min(parent sizes)``; records holding more than ``MAX_KEYS``
@@ -40,6 +44,7 @@ from . import u64, hashing, segments, sketches
 from .u64 import U64
 
 INT32_MAX = np.iinfo(np.int32).max
+_SENT32 = np.uint32(0xFFFFFFFF)
 logger = logging.getLogger(__name__)
 
 
@@ -145,111 +150,134 @@ def rough_oversize_detection(cfg: HDBConfig, key: U64, valid: jnp.ndarray,
     return right, keep, dropped_sim, s
 
 
-def dedupe_oversized_reps(r_xhi: jnp.ndarray, r_xlo: jnp.ndarray,
-                          r_sz: jnp.ndarray, r_khi: jnp.ndarray,
-                          r_klo: jnp.ndarray):
+def dedupe_oversized_reps(r_xhi, r_xlo, r_sz, r_khi, r_klo):
     """Deduplicate over-sized block representatives (Alg. 4 lines 6-9).
 
     One representative per over-sized block, described by its membership
     fingerprint ``(r_xhi, r_xlo)``, exact size ``r_sz`` and block key
-    ``(r_khi, r_klo)``; invalid lanes carry sentinel keys/fingerprints and
-    ``INT32_MAX`` size. Blocks with identical (fingerprint, size) are
-    duplicates; the smallest key of each group survives.
+    ``(r_khi, r_klo)``; lanes with the sentinel key are padding. Blocks
+    with identical (fingerprint, size) are duplicates; the smallest key
+    of each group survives.
 
-    Shared by the batch iteration (reps extracted from the global sort)
-    and the streaming delta path (reps taken from the BlockStore key
-    table). Returns:
-      table: ((t_khi, t_klo), t_sz) survivor keys sorted by key
+    Host numpy, shared by the batch iteration (reps pulled from the
+    device between its two steps) and the streaming delta path (reps
+    taken from the BlockStore key table). It runs on the host because
+    the live representatives are few next to the entries, while a 5-key
+    device sort of the fixed-capacity buffer takes minutes of TPU
+    compile time per shape. Returns:
       n_dup: number of duplicate representatives dropped
       survivor_in: bool mask aligned with the INPUT lanes marking survivors
     """
-    m = r_khi.shape[0]
-    orig = jnp.arange(m, dtype=jnp.int32)
-    # sort by (xor, size, key): duplicates (same membership) become adjacent;
-    # the smallest key of each duplicate group survives (full lexicographic
-    # sort makes the survivor deterministic).
-    r_xhi, r_xlo, r_sz, r_khi, r_klo, orig = jax.lax.sort(
-        (r_xhi, r_xlo, r_sz, r_khi, r_klo, orig), num_keys=5)
-    same_prev = (
-        (r_xhi == jnp.roll(r_xhi, 1)) & (r_xlo == jnp.roll(r_xlo, 1))
-        & (r_sz == jnp.roll(r_sz, 1)))
-    same_prev = same_prev.at[0].set(False)
-    rep_valid_sorted = ~((r_khi == jnp.uint32(0xFFFFFFFF)) & (r_klo == jnp.uint32(0xFFFFFFFF)))
-    survivor = rep_valid_sorted & ~same_prev
-    n_dup = jnp.sum((rep_valid_sorted & same_prev).astype(jnp.int32))
-
-    # survivor table sorted by key for O(log) lookups (the paper's
-    # "broadcasted counts map")
-    t_khi = jnp.where(survivor, r_khi, jnp.uint32(0xFFFFFFFF))
-    t_klo = jnp.where(survivor, r_klo, jnp.uint32(0xFFFFFFFF))
-    t_sz = jnp.where(survivor, r_sz, 0)
-    t_khi, t_klo, t_sz = jax.lax.sort((t_khi, t_klo, t_sz), num_keys=2)
-    table = ((t_khi, t_klo), t_sz)
-    survivor_in = jnp.zeros((m,), bool).at[orig].set(survivor)
-    return table, n_dup, survivor_in
+    r_xhi, r_xlo, r_khi, r_klo = (np.asarray(x, np.uint32)
+                                  for x in (r_xhi, r_xlo, r_khi, r_klo))
+    r_sz = np.asarray(r_sz, np.int32)
+    live = np.flatnonzero(~((r_khi == _SENT32) & (r_klo == _SENT32)))
+    # (fingerprint, size, key) order: duplicates adjacent, smallest key first
+    order = live[np.lexsort((r_klo[live], r_khi[live], r_sz[live],
+                             r_xlo[live], r_xhi[live]))]
+    xhi, xlo, sz = r_xhi[order], r_xlo[order], r_sz[order]
+    dup = np.zeros(len(order), bool)
+    dup[1:] = (xhi[1:] == xhi[:-1]) & (xlo[1:] == xlo[:-1]) & (sz[1:] == sz[:-1])
+    survivor_in = np.zeros(len(r_khi), bool)
+    survivor_in[order[~dup]] = True
+    return int(dup.sum()), survivor_in
 
 
-survivor_reps = jax.jit(dedupe_oversized_reps)
+def count_exact(cfg: HDBConfig, key: U64, keep: jnp.ndarray):
+    """Algorithm 4, device half (single-shard fast path — see
+    core/distributed.py for the all_to_all + Bloom-broadcast variant).
 
-
-def exactly_count_and_dedupe(cfg: HDBConfig, key: U64, keep: jnp.ndarray):
-    """Algorithm 4 (single-shard fast path — see core/distributed.py for the
-    all_to_all + Bloom-broadcast variant).
-
-    Returns dense (same shape as keep):
-      right_exact: mask of entries whose block the CMS over-counted
-      survive:     mask of entries on surviving (deduped) over-sized blocks
-      size:        exact block size for `survive` entries
-      plus (survivor key table, diagnostics) for downstream use.
+    Sorts the surviving entries by key; segmented count and XOR of rid
+    fingerprints give every entry its exact block size and membership
+    fingerprint. Returns ``(counted, reps, n_reps, rep_overflow)``:
+    ``counted`` holds the key-sorted entries ``classify_exact`` needs,
+    ``reps`` one (fingerprint, size, key) representative per over-sized
+    block in a ``rep_capacity`` buffer, in key order, padded with
+    sentinels — the input of the host ``dedupe_oversized_reps``.
     """
     n, k = keep.shape
     flat = keep.reshape(-1)
     nk = n * k
-    rid = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None], (n, k)).reshape(-1)
     khi = jnp.where(flat, key[0].reshape(-1), jnp.uint32(0xFFFFFFFF))
     klo = jnp.where(flat, key[1].reshape(-1), jnp.uint32(0xFFFFFFFF))
     orig = jnp.arange(nk, dtype=jnp.int32)
-    (shi, slo), (srid, sorig) = segments.sort_by_key((khi, klo), [rid, orig])
+    (shi, slo), (sorig,) = segments.sort_by_key((khi, klo), [orig])
+    srid = sorig // k  # entry (row, col) sits at flat index row * k + col
     skey = (shi, slo)
     live = ~u64.is_sentinel(skey)
     sizes = segments.segment_counts(skey)
     fp = hashing.fingerprint_rid(srid)
     fp = (jnp.where(live, fp[0], 0), jnp.where(live, fp[1], 0))
     xors = segments.segment_xor(skey, fp)
-
     over = live & (sizes > cfg.max_block_size)
-    right_exact_sorted = live & ~over
 
-    # --- dedupe over-sized blocks by membership fingerprint (XOR, size) ---
     reps = segments.segment_starts(skey) & over
-    n_reps = jnp.sum(reps.astype(jnp.int32))
+    # each entry's block ordinal among the over-sized blocks, in key
+    # order: reps mark only a block's first entry, so the running count
+    # is constant along a block
+    rep_ord = jnp.cumsum(reps.astype(jnp.int32)) - 1
+    n_reps = rep_ord[-1] + 1
     rep_idx = jnp.nonzero(reps, size=cfg.rep_capacity, fill_value=nk - 1)[0]
     rep_valid = jnp.arange(cfg.rep_capacity, dtype=jnp.int32) < n_reps
     rep_overflow = jnp.maximum(n_reps - cfg.rep_capacity, 0)
-    r_xhi = jnp.where(rep_valid, xors[0][rep_idx], jnp.uint32(0xFFFFFFFF))
-    r_xlo = jnp.where(rep_valid, xors[1][rep_idx], jnp.uint32(0xFFFFFFFF))
-    r_sz = jnp.where(rep_valid, sizes[rep_idx], INT32_MAX)
-    r_khi = jnp.where(rep_valid, shi[rep_idx], jnp.uint32(0xFFFFFFFF))
-    r_klo = jnp.where(rep_valid, slo[rep_idx], jnp.uint32(0xFFFFFFFF))
-    table, n_dup, survivor = dedupe_oversized_reps(r_xhi, r_xlo, r_sz,
-                                                   r_khi, r_klo)
-    (t_khi, t_klo), t_sz = table
+    rep_lanes = (
+        jnp.where(rep_valid, xors[0][rep_idx], jnp.uint32(0xFFFFFFFF)),
+        jnp.where(rep_valid, xors[1][rep_idx], jnp.uint32(0xFFFFFFFF)),
+        jnp.where(rep_valid, sizes[rep_idx], INT32_MAX),
+        jnp.where(rep_valid, shi[rep_idx], jnp.uint32(0xFFFFFFFF)),
+        jnp.where(rep_valid, slo[rep_idx], jnp.uint32(0xFFFFFFFF)))
+    counted = {"sorig": sorig, "sizes": sizes, "live": live, "over": over,
+               "rep_ord": rep_ord}
+    return counted, rep_lanes, n_reps, rep_overflow
 
-    # classify sorted entries: over-sized entries survive iff their key is in
-    # the survivor table (duplicates' keys are absent -> dropped).
-    hit, _ = segments.lookup_u64((t_khi, t_klo), t_sz, skey, 0)
-    survive_sorted = over & hit
 
-    # scatter back to dense layout
-    def unsort(x_sorted, fill):
-        out = jnp.full((nk,), fill, x_sorted.dtype)
-        return out.at[sorig].set(x_sorted)
+def classify_exact(keep: jnp.ndarray, counted, survivor: jnp.ndarray):
+    """Algorithm 4, second device half: classify the key-sorted entries
+    given ``survivor``, the host dedupe's flag for each representative
+    lane of ``count_exact`` (``rep_capacity`` long).
 
-    right_exact = unsort(right_exact_sorted, False).reshape(n, k) & keep
-    survive = unsort(survive_sorted, False).reshape(n, k) & keep
-    size = unsort(jnp.where(live, sizes, 0), 0).reshape(n, k)
-    n_survivors = jnp.sum(survivor.astype(jnp.int32))
-    return right_exact, survive, size, table, n_dup, n_survivors, rep_overflow
+    Returns dense masks shaped like ``keep``:
+      right_exact: entries whose block the CMS over-counted
+      survive:     entries on surviving (deduped) over-sized blocks
+      size:        exact block size for ``survive`` entries
+    """
+    n, k = keep.shape
+    nk = n * k
+    assert nk < 1 << 30, "block sizes must fit the 30-bit field below"
+    cap = survivor.shape[0]
+    sorig, rep_ord = counted["sorig"], counted["rep_ord"]
+    live, over = counted["live"], counted["over"]
+    # an over-sized entry survives iff its block's representative did
+    # (duplicates were dropped; blocks past rep_capacity had no lane)
+    survive_sorted = (over & (rep_ord < cap)
+                      & survivor[jnp.clip(rep_ord, 0, cap - 1)])
+
+    # one scatter back to the dense layout: the size in the low 30 bits,
+    # the two masks in the top two
+    code = (jnp.where(live, counted["sizes"], 0).astype(jnp.uint32)
+            | (survive_sorted.astype(jnp.uint32) << 30)
+            | ((live & ~over).astype(jnp.uint32) << 31))
+    dense = jnp.zeros((nk,), jnp.uint32).at[sorig].set(code).reshape(n, k)
+    right_exact = (dense >> 31).astype(bool) & keep
+    survive = ((dense >> 30) & 1).astype(bool) & keep
+    size = (dense & jnp.uint32((1 << 30) - 1)).astype(jnp.int32)
+    return right_exact, survive, size
+
+
+def pad_to_intersect_width(cfg: HDBConfig, key: U64, valid: jnp.ndarray,
+                           psize: jnp.ndarray):
+    """Pad ``intersect_keys``' output with invalid sentinel columns to
+    ``cfg.intersect_width``, so that every iteration after the first has
+    one shape and compiles once. Changes no result: an invalid column
+    holds no entry."""
+    pad = cfg.intersect_width - valid.shape[1]
+    if pad <= 0:
+        return key, valid, psize
+    cols = ((0, 0), (0, pad))
+    sent = jnp.uint32(0xFFFFFFFF)
+    return ((jnp.pad(key[0], cols, constant_values=sent),
+             jnp.pad(key[1], cols, constant_values=sent)),
+            jnp.pad(valid, cols), jnp.pad(psize, cols))
 
 
 def intersect_keys(cfg: HDBConfig, key: U64, survive: jnp.ndarray,
@@ -296,27 +324,57 @@ def intersect_keys(cfg: HDBConfig, key: U64, survive: jnp.ndarray,
 
 
 @functools.partial(jax.jit, static_argnums=0)
-def hdb_iteration(cfg: HDBConfig, keys_packed: jnp.ndarray, valid: jnp.ndarray,
-                  psize: jnp.ndarray):
-    """One full HDB iteration. Returns (accepted_mask, new_state, stats)."""
+def _count_step(cfg: HDBConfig, keys_packed: jnp.ndarray, valid: jnp.ndarray,
+                psize: jnp.ndarray):
     key = (keys_packed[..., 0], keys_packed[..., 1])
     right_cms, keep, dropped_sim, _ = rough_oversize_detection(cfg, key, valid, psize)
-    (right_exact, survive, size, _table, n_dup, n_survivors,
-     rep_overflow) = exactly_count_and_dedupe(cfg, key, keep)
+    counted, reps, n_reps, rep_overflow = count_exact(cfg, key, keep)
+    return (right_cms, keep, dropped_sim), counted, reps, n_reps, rep_overflow
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _intersect_step(cfg: HDBConfig, keys_packed: jnp.ndarray,
+                    valid: jnp.ndarray, rough, counted, survivor):
+    key = (keys_packed[..., 0], keys_packed[..., 1])
+    right_cms, keep, dropped_sim = rough
+    right_exact, survive, size = classify_exact(keep, counted, survivor)
     accepted = right_cms | right_exact
     new_key, new_valid, new_psize, n_dropped_mk = intersect_keys(cfg, key, survive, size)
+    new_key, new_valid, new_psize = pad_to_intersect_width(
+        cfg, new_key, new_valid, new_psize)
     stats = {
         "n_live_keys": jnp.sum(valid.astype(jnp.int32)),
         "n_right_cms": jnp.sum(right_cms.astype(jnp.int32)),
         "n_right_exact": jnp.sum(right_exact.astype(jnp.int32)),
         "n_dropped_similarity": jnp.sum(dropped_sim.astype(jnp.int32)),
         "n_dropped_max_keys": n_dropped_mk,
-        "n_duplicate_blocks": n_dup,
-        "n_surviving_oversized": n_survivors,
         "n_surviving_entries": jnp.sum(survive.astype(jnp.int32)),
-        "rep_overflow": rep_overflow,
     }
     new_state = (jnp.stack([new_key[0], new_key[1]], axis=-1), new_valid, new_psize)
+    return accepted, new_state, stats
+
+
+def hdb_iteration(cfg: HDBConfig, keys_packed: jnp.ndarray, valid: jnp.ndarray,
+                  psize: jnp.ndarray):
+    """One full HDB iteration. Returns (accepted_mask, new_state, stats).
+
+    Two jitted device steps (CMS + exact counts; classification +
+    intersection) around the host dedupe of the over-sized
+    representatives, whose survivor flags go back as one fixed
+    ``rep_capacity`` mask so the second step compiles once per key width.
+    """
+    rough, counted, reps, n_reps, rep_overflow = _count_step(
+        cfg, keys_packed, valid, psize)
+    m = min(int(n_reps), cfg.rep_capacity)
+    n_dup, survivor = dedupe_oversized_reps(
+        *(np.asarray(r)[:m] for r in reps))
+    flags = np.zeros(cfg.rep_capacity, bool)
+    flags[:m] = survivor
+    accepted, new_state, stats = _intersect_step(
+        cfg, keys_packed, valid, rough, counted, jax.device_put(flags))
+    stats.update(n_duplicate_blocks=n_dup,
+                 n_surviving_oversized=int(survivor.sum()),
+                 rep_overflow=int(rep_overflow))
     return accepted, new_state, stats
 
 

@@ -12,11 +12,11 @@ cross-block dedupe therefore runs on device through the
   implementation (the original shift-method enumeration + lexsort
   dedupe); ``"jax"`` decodes pair slots with fused XLA integer ops;
   ``"pallas"`` routes the triangular decode through the Pallas TPU kernel
-  (interpret mode on CPU). ``"auto"`` picks ``"jax"`` when the int32
-  device contract holds (all rids < 2**31, block sizes <=
+  (interpreted on the CPU backend only). ``"auto"`` picks ``"jax"`` when
+  the int32 device contract holds (all rids < 2**31, block sizes <=
   ``kernels.pairs.MAX_BLOCK_N``, budget < 2**31) and falls back to numpy
-  otherwise; ``"distributed"`` dispatches to the fingerprint-routed
-  shard-local dedupe over a device mesh
+  with a warning otherwise; ``"distributed"`` dispatches to the
+  fingerprint-routed shard-local dedupe over a device mesh
   (``core.distributed.dedupe_pairs_distributed``).
 - chunking contract: device backends enumerate the canonical pair-slot
   space (blocks in CSR order, row-major triangle within a block — see
@@ -34,14 +34,16 @@ cross-block dedupe therefore runs on device through the
   ``b(i,j,n) = i*(n-1) - (i-1)*i/2 + j - i - 1`` for compactly shipping a
   filtered subset of a block's pairs to pairwise matching.
 
-Measured on this container's CPU (benchmarks/bench_pairs.py, 1M pair
+Measured on a CPU backend only (benchmarks/bench_pairs.py, 1M pair
 slots): the numpy path is enumeration-bound and the device path
 sort-bound; the crossover is around ~10k pair slots — below that, jit
 dispatch overhead dominates and ``backend="numpy"`` wins; above it the
 JAX path is ~5.6x faster on many-small-block layouts (the shift method's
 worst case: one pass per diagonal offset), ~5.2x on medium (16-64) and
 ~2.4-2.5x on large/zipf layouts where numpy's per-block meshgrid path is
-less penalized. Pallas interpret-mode timings are parity checks only.
+less penalized. That crossover is a CPU number, so ``"auto"`` applies
+it on the CPU backend only; on an accelerator every pair set takes the
+device path. Pallas interpret-mode timings are parity checks only.
 
 sort_backend (the dedupe-sort knob, threaded through every device
 dedupe call site down to ``kernels/sort``): ``"auto"`` keeps the
@@ -215,20 +217,26 @@ def _device_contract_ok(blocks: Blocks, budget: int) -> Optional[str]:
     return None
 
 
-def _resolve_backend(backend: str, blocks: Blocks, budget: int) -> str:
+def resolve_backend(backend: str, blocks: Blocks, budget: int) -> str:
+    """The single-device pairs backend that ``backend`` runs as here.
+
+    ``"auto"`` is ``"jax"`` unless the layout is below the CPU crossover
+    on the CPU backend. Any device backend whose int32 contract fails
+    warns and runs ``"numpy"`` — never silently.
+    """
     if backend not in _BACKENDS:
         raise ValueError(f"backend must be one of {_BACKENDS}, got {backend!r}")
     assert backend != "distributed"  # dispatched before resolution
     if backend == "numpy":
         return "numpy"
-    if backend == "auto" and blocks.num_pair_slots < _AUTO_NUMPY_CROSSOVER:
+    if (backend == "auto" and blocks.num_pair_slots < _AUTO_NUMPY_CROSSOVER
+            and jax.default_backend() == "cpu"):
         return "numpy"
     reason = _device_contract_ok(blocks, budget)
     if reason is None:
         return "jax" if backend == "auto" else backend
-    if backend != "auto":
-        warnings.warn(f"pairs backend {backend!r} unavailable ({reason}); "
-                      "falling back to numpy", RuntimeWarning, stacklevel=3)
+    warnings.warn(f"pairs backend {backend!r} unavailable ({reason}); "
+                  "falling back to numpy", RuntimeWarning, stacklevel=3)
     return "numpy"
 
 
@@ -309,7 +317,7 @@ def _radix_passes_for_blocks(blocks: Blocks) -> int:
         int(blocks.members.max()) if len(blocks.members) else 0)
 
 
-def _resolve_sort_backend(sort_backend: str, blocks: Blocks) -> str:
+def resolve_sort_backend(sort_backend: str, blocks: Blocks) -> str:
     """Map the user knob onto a concrete dedupe-sort strategy.
 
     Returns one of "host" (packed u64 ``np.sort`` — CPU only, where host
@@ -339,11 +347,11 @@ def _resolve_sort_backend(sort_backend: str, blocks: Blocks) -> str:
 
 
 def _dedupe_device(blocks: Blocks, slots: Optional[np.ndarray], total: int,
-                   chunk_pairs: int, use_kernel: bool, interpret: bool,
+                   chunk_pairs: int, use_kernel: bool,
                    sort_backend: str = "auto") -> Tuple[np.ndarray, ...]:
     """Device engine: chunked slot decode + one sort-dedupe pass.
 
-    The dedupe sort strategy comes from ``_resolve_sort_backend``:
+    The dedupe sort strategy comes from ``resolve_sort_backend``:
     ``"auto"`` packs the words on device and sorts with ``np.sort`` on
     the CPU backend (host == device memory there, and numpy's u64 sort
     is ~40x faster than XLA CPU's comparator sort) and radix-sorts on
@@ -368,8 +376,7 @@ def _dedupe_device(blocks: Blocks, slots: Optional[np.ndarray], total: int,
             a, b, s, v = pairs_kernels.decode_chunk(
                 cum32, start32, size32, mem32,
                 jax.device_put(np.int32(base)), total32,
-                chunk=chunk, steps=steps, use_kernel=use_kernel,
-                interpret=interpret)
+                chunk=chunk, steps=steps, use_kernel=use_kernel)
             out_a.append(a); out_b.append(b); out_s.append(s); out_v.append(v)
     else:
         # sampled path: slots are int64 host-side; split block/local on
@@ -390,12 +397,12 @@ def _dedupe_device(blocks: Blocks, slots: Optional[np.ndarray], total: int,
             a, b, s, v = pairs_kernels.decode_block_local(
                 start32, size32, mem32, jnp.asarray(block[sl]),
                 jnp.asarray(local[sl]), jnp.asarray(valid[sl]),
-                steps=steps, use_kernel=use_kernel, interpret=interpret)
+                steps=steps, use_kernel=use_kernel)
             out_a.append(a); out_b.append(b); out_s.append(s); out_v.append(v)
     if not out_a:
         z = np.zeros((0,), np.int64)
         return z, z, z, None
-    sort_kind = _resolve_sort_backend(sort_backend, blocks)
+    sort_kind = resolve_sort_backend(sort_backend, blocks)
     if sort_kind == "host":
         his, los = [], []
         for a, b, s, v in zip(out_a, out_b, out_s, out_v):
@@ -412,8 +419,7 @@ def _dedupe_device(blocks: Blocks, slots: Optional[np.ndarray], total: int,
     sa, sb, ss, winner = pairs_kernels.dedupe_device(
         jnp.concatenate(out_a), jnp.concatenate(out_b),
         jnp.concatenate(out_s), jnp.concatenate(out_v),
-        sort_backend=sort_kind, use_kernel=use_kernel, interpret=interpret,
-        **kw)
+        sort_backend=sort_kind, use_kernel=use_kernel, **kw)
     # compact host-side (the winner count is data-dependent, so the mask
     # gather can't stay on device without a dynamic shape; indexing the
     # device array with a host mask would be an implicit transfer) and
@@ -428,7 +434,7 @@ def _dedupe_device(blocks: Blocks, slots: Optional[np.ndarray], total: int,
 
 def dedupe_pairs(blocks: Blocks, budget: int = 50_000_000,
                  backend: str = "auto", chunk_pairs: int = 1 << 20,
-                 sample_seed: int = 0, interpret: bool = True,
+                 sample_seed: int = 0,
                  mesh=None, axis_names: Tuple[str, ...] = ("data",),
                  route_slack: float = 2.0,
                  sort_backend: str = "auto") -> PairSet:
@@ -443,7 +449,7 @@ def dedupe_pairs(blocks: Blocks, budget: int = 50_000_000,
     ``sort_backend`` selects the dedupe-sort engine of the device
     backends (``"comparator"`` = ``lax.sort``, ``"radix"`` = the
     ``kernels/sort`` LSB radix kernel over packed words, ``"auto"`` =
-    the measured per-platform winner — see ``_resolve_sort_backend``);
+    the measured per-platform winner — see ``resolve_sort_backend``);
     every choice is bit-identical, only speed differs (measured
     crossover in the module docstring). The numpy backend ignores it.
 
@@ -471,18 +477,16 @@ def dedupe_pairs(blocks: Blocks, budget: int = 50_000_000,
         return dist_lib.dedupe_pairs_distributed(
             blocks, mesh, axis_names, budget=budget,
             chunk_per_shard=chunk_pairs, route_slack=route_slack,
-            interpret=interpret, sample_seed=sample_seed,
-            sort_backend=sort_backend)
+            sample_seed=sample_seed, sort_backend=sort_backend)
     exact = total <= budget
     slots = None if exact else _sample_slots(total, budget, sample_seed)
-    backend = _resolve_backend(backend, blocks, budget)
+    backend = resolve_backend(backend, blocks, budget)
     if backend == "numpy":
         a, b, s = _dedupe_numpy(blocks, slots)
         dev = None
     else:
         a, b, s, dev = _dedupe_device(blocks, slots, total, chunk_pairs,
                                       use_kernel=(backend == "pallas"),
-                                      interpret=interpret,
                                       sort_backend=sort_backend)
     return PairSet(a, b, s, exact, total,
                    device_a=None if dev is None else dev[0],
@@ -490,7 +494,7 @@ def dedupe_pairs(blocks: Blocks, budget: int = 50_000_000,
 
 
 def enumerate_pairs(blocks: Blocks, backend: str = "auto",
-                    chunk_pairs: int = 1 << 20, interpret: bool = True
+                    chunk_pairs: int = 1 << 20
                     ) -> Iterator[Tuple[np.ndarray, np.ndarray, np.ndarray]]:
     """Stream raw (a, b, block_size) numpy chunks WITHOUT dedupe.
 
@@ -508,7 +512,7 @@ def enumerate_pairs(blocks: Blocks, backend: str = "auto",
     # device's int32 slot indices (dedupe_pairs only needs budget to fit —
     # its sampled path never materializes global slot indices on device);
     # min() maps an overflowing total onto the budget >= INT32_MAX check.
-    backend = _resolve_backend(backend, blocks,
+    backend = resolve_backend(backend, blocks,
                                budget=min(blocks.num_pair_slots, INT32_MAX))
     if backend == "numpy":
         yield from iter_block_pairs(blocks, chunk_pairs)
@@ -527,8 +531,7 @@ def enumerate_pairs(blocks: Blocks, backend: str = "auto",
         a, b, s, v = pairs_kernels.decode_chunk(
             cum32, start32, size32, mem32,
             jax.device_put(np.int32(base)), total32,
-            chunk=chunk, steps=steps, use_kernel=(backend == "pallas"),
-            interpret=interpret)
+            chunk=chunk, steps=steps, use_kernel=(backend == "pallas"))
         vm = np.asarray(v)
         yield (np.asarray(a)[vm].astype(np.int64),
                np.asarray(b)[vm].astype(np.int64),

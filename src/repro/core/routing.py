@@ -11,14 +11,15 @@ shrinking survivor set) or fall back (pair dedupe must stay exact).
 
 ``linear_shard_index`` linearizes a multi-axis mesh position into the
 flat shard id used by ``owner % n_shards`` routing. Axis sizes are taken
-from the mesh *statically* (``jax.lax.axis_size`` does not exist on the
-pinned JAX version, and sizes are compile-time constants anyway).
+from the mesh *statically*: they are compile-time constants, and the
+static ``int`` keeps the linearization free of traced arithmetic (the
+traced ``jax.lax.axis_size`` would give the same value).
 
 Ownership seeds are shared constants: ``KEY_OWNER_SEED`` partitions
 64-bit block keys (the HDB exact-count exchange AND the sharded
 streaming ``BlockStore``'s key-table/CMS/CSR slices — same partition, so
 a batch shard and a streaming shard agree on who owns a key) and
-``REP_OWNER_SEED`` partitions membership fingerprints / pair packs.
+``REP_OWNER_SEED`` partitions pair packs (the streaming pair ledger).
 ``np_owner_u64`` is the bit-exact host mirror of the device rule
 (low 32 hash bits mod n_shards), letting host-resident streaming state
 route without staging keys through the device.

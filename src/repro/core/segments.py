@@ -39,6 +39,20 @@ def segment_starts(key: U64) -> jnp.ndarray:
     return first | ~u64.eq(key, prev)
 
 
+def _cum_xor(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix XOR of a 1-D integer array.
+
+    Written as the full-window ``reduce_window`` that ``lax.cumsum``
+    itself lowers to on TPU (XLA rewrites it into a linear-work scan).
+    ``lax.associative_scan`` unrolls into ~2 log2(n) levels of strided
+    slices, which the TPU compiler took over 8 GB and minutes to compile
+    at 15M entries.
+    """
+    n = x.shape[0]
+    return jax.lax.reduce_window(x, jnp.zeros((), x.dtype), jax.lax.bitwise_xor,
+                                 (n,), (1,), ((n - 1, 0),))
+
+
 def segment_ids(starts: jnp.ndarray) -> jnp.ndarray:
     """Monotone segment id per element from a start mask."""
     return jnp.cumsum(starts.astype(jnp.int32)) - 1
@@ -53,11 +67,9 @@ def segment_counts(key: U64) -> jnp.ndarray:
     starts = segment_starts(key)
     idx = jnp.arange(n, dtype=jnp.int32)
     # position of my segment's start
-    start_pos = jnp.where(starts, idx, 0)
-    start_pos = jax.lax.associative_scan(jnp.maximum, start_pos)
+    start_pos = jax.lax.cummax(jnp.where(starts, idx, 0))
     # position of my segment's end (exclusive): scan from the right
-    end_pos = jnp.where(starts, idx, n)
-    end_pos = jax.lax.associative_scan(jnp.minimum, end_pos, reverse=True)
+    end_pos = jax.lax.cummin(jnp.where(starts, idx, n), reverse=True)
     # end_pos currently holds the NEXT start among [i..); for elements of the
     # last run that's n via the init fill. But careful: scan-min from right of
     # start positions: for element i, min over j>=i of (starts[j] ? j : n)
@@ -78,11 +90,11 @@ def segment_xor(key: U64, value: U64) -> U64:
     n = key[0].shape[0]
     starts = segment_starts(key)
     idx = jnp.arange(n, dtype=jnp.int32)
-    start_pos = jax.lax.associative_scan(jnp.maximum, jnp.where(starts, idx, 0))
+    start_pos = jax.lax.cummax(jnp.where(starts, idx, 0))
     sizes = segment_counts(key)
     end_pos = start_pos + sizes - 1  # inclusive
-    cum_hi = jax.lax.associative_scan(jnp.bitwise_xor, value[0])
-    cum_lo = jax.lax.associative_scan(jnp.bitwise_xor, value[1])
+    cum_hi = _cum_xor(value[0])
+    cum_lo = _cum_xor(value[1])
     before = start_pos - 1
     pre_hi = jnp.where(before >= 0, cum_hi[jnp.maximum(before, 0)], 0).astype(jnp.uint32)
     pre_lo = jnp.where(before >= 0, cum_lo[jnp.maximum(before, 0)], 0).astype(jnp.uint32)

@@ -22,8 +22,8 @@ from ..kernels.match import ops as match_ops
 
 # fused-match backend knob: "host" is the score-on-host parity baseline,
 # "jnp"/"pallas" keep the matched pair set on device (kernels/match);
-# "auto" currently resolves to the jnp mirror (interpret-mode Pallas is
-# emulation-speed on CPU — the same policy as the pairs/sort kernels)
+# "auto" resolves to the jnp mirror on every platform until a chip
+# measurement shows the kernel ahead (the pairs "auto" policy)
 MATCH_BACKENDS = ("auto", "host", "jnp", "pallas")
 
 
@@ -134,8 +134,7 @@ def match_pairs(columns, a, b, cfg: MatcherConfig = MatcherConfig()) -> np.ndarr
 def match_compact(columns: Dict[str, TokenColumn], a, b,
                   cfg: MatcherConfig = MatcherConfig(), *,
                   backend: str = "auto",
-                  chunk: int = match_ops.DEFAULT_CHUNK,
-                  interpret: bool = True):
+                  chunk: int = match_ops.DEFAULT_CHUNK):
     """Fused on-device match: score + threshold + compaction, no host hop.
 
     ``a``/``b`` are the candidate pair list — device buffers
@@ -147,8 +146,8 @@ def match_compact(columns: Dict[str, TokenColumn], a, b,
     (``kernels.match.packed_host`` reassembles them) — and the tail is
     (0, 0) padding that feeds straight into ``cluster_pairs_device`` as
     frontier no-ops. Backend "pallas" runs the fused Pallas kernel
-    (interpret-mode off-TPU), "jnp"/"auto" the XLA mirror; both are
-    bit-identical to ``match_pairs``.
+    (interpreted on the CPU backend only), "jnp"/"auto" the XLA mirror;
+    both are bit-identical to ``match_pairs``.
     """
     resolved = resolve_match_backend(backend)
     if resolved == "host":
@@ -163,5 +162,4 @@ def match_compact(columns: Dict[str, TokenColumn], a, b,
         b = jnp.asarray(np.asarray(b, np.int32))
     return match_ops.fused_match_pairs(
         tokens, masks, weights, a, b, threshold=cfg.threshold,
-        n_real=n_real, chunk=chunk, use_kernel=(resolved == "pallas"),
-        interpret=interpret)
+        n_real=n_real, chunk=chunk, use_kernel=(resolved == "pallas"))

@@ -57,6 +57,10 @@ class DedupReport:
     partition_seconds: float
     survivors: np.ndarray       # (S,) record ids, one per component
     component_of: np.ndarray    # (N,) component label per record
+    # batch runs only: the blocking stage's blocks and candidate pairs,
+    # for callers that check them against a reference
+    blocks: Optional[pairs_mod.Blocks] = None
+    pairs: Optional[pairs_mod.PairSet] = None
 
 
 def dedup_corpus(corpus: Corpus,
@@ -68,8 +72,6 @@ def dedup_corpus(corpus: Corpus,
                  match_backend: str = "auto",
                  cc_max_rounds: int = 64) -> DedupReport:
     n = corpus.num_records
-    backend = ("host" if match_backend == "host"
-               else matcher.resolve_match_backend(match_backend))
     t0 = time.perf_counter()
     keys, valid = blocks_mod.build_keys(corpus.columns, corpus.blocking)
     if blocker == "hdb":
@@ -83,36 +85,10 @@ def dedup_corpus(corpus: Corpus,
     pset = pairs_mod.dedupe_pairs(blk, budget=pair_budget)
     # feed the matcher the device pair buffer directly (no host round trip
     # of the pair list when the device dedupe path produced it)
-    dev_a, dev_b = pset.pair_buffers()
-    _sync(dev_a, dev_b)
+    _sync(pset.pair_buffers())
     t1 = time.perf_counter()
-    if backend == "host":
-        # parity baseline: scores + matched mask land host-side, the
-        # matched pair list is gathered in numpy and re-uploaded for CC
-        matched = matcher.match_pairs(corpus.columns, dev_a, dev_b, match_cfg)
-        ma, mb = pset.a[matched], pset.b[matched]
-        num_matched = int(matched.sum())
-        t2 = time.perf_counter()
-        label = components.connected_components(n, ma, mb,
-                                                max_rounds=cc_max_rounds)
-        # canonical survivor = min record id per component == the label
-        survivors = np.unique(label)
-    else:
-        # fused path: matched pairs stay device-resident end to end —
-        # the compacted (0,0)-padded buffer flows straight into CC and
-        # only labels/survivors/counters ever cross to the host
-        ca, cb, cnt = matcher.match_compact(corpus.columns, dev_a, dev_b,
-                                            match_cfg, backend=backend)
-        _sync(ca, cb, cnt)
-        t2 = time.perf_counter()
-        label_d, surv_d, n_surv, converged, _ = components.cluster_pairs_device(
-            n, ca, cb, max_rounds=cc_max_rounds)
-        _sync(label_d, surv_d)
-        if not bool(np.asarray(converged)):
-            components._warn_truncated(cc_max_rounds)
-        num_matched = int(np.asarray(cnt))
-        label = np.asarray(label_d)[:n].astype(np.int64)
-        survivors = np.asarray(surv_d)[:int(np.asarray(n_surv))].astype(np.int64)
+    num_matched, label, survivors, t2 = match_and_cluster(
+        corpus, pset, match_cfg, match_backend, cc_max_rounds)
     t3 = time.perf_counter()
     return DedupReport(
         num_records=n,
@@ -125,7 +101,51 @@ def dedup_corpus(corpus: Corpus,
         partition_seconds=t3 - t2,
         survivors=survivors,
         component_of=label,
+        blocks=blk,
+        pairs=pset,
     )
+
+
+def match_and_cluster(corpus: Corpus, pset: pairs_mod.PairSet,
+                      match_cfg: matcher.MatcherConfig = matcher.MatcherConfig(),
+                      match_backend: str = "auto", cc_max_rounds: int = 64):
+    """The batch back half: match the candidate pairs, then cluster.
+
+    Returns ``(num_matched, component_of, survivors, t_matched)``, where
+    ``t_matched`` is the ``perf_counter`` reading between the two stages.
+    """
+    n = corpus.num_records
+    backend = ("host" if match_backend == "host"
+               else matcher.resolve_match_backend(match_backend))
+    dev_a, dev_b = pset.pair_buffers()
+    if backend == "host":
+        # parity baseline: scores + matched mask land host-side, the
+        # matched pair list is gathered in numpy and re-uploaded for CC
+        matched = matcher.match_pairs(corpus.columns, dev_a, dev_b, match_cfg)
+        ma, mb = pset.a[matched], pset.b[matched]
+        num_matched = int(matched.sum())
+        t_matched = time.perf_counter()
+        label = components.connected_components(n, ma, mb,
+                                                max_rounds=cc_max_rounds)
+        # canonical survivor = min record id per component == the label
+        survivors = np.unique(label)
+    else:
+        # fused path: matched pairs stay device-resident end to end —
+        # the compacted (0,0)-padded buffer flows straight into CC and
+        # only labels/survivors/counters ever cross to the host
+        ca, cb, cnt = matcher.match_compact(corpus.columns, dev_a, dev_b,
+                                            match_cfg, backend=backend)
+        _sync(ca, cb, cnt)
+        t_matched = time.perf_counter()
+        label_d, surv_d, n_surv, converged, _ = components.cluster_pairs_device(
+            n, ca, cb, max_rounds=cc_max_rounds)
+        _sync(label_d, surv_d)
+        if not bool(np.asarray(converged)):
+            components._warn_truncated(cc_max_rounds)
+        num_matched = int(np.asarray(cnt))
+        label = np.asarray(label_d)[:n].astype(np.int64)
+        survivors = np.asarray(surv_d)[:int(np.asarray(n_surv))].astype(np.int64)
+    return num_matched, label, survivors, t_matched
 
 
 class DedupPipeline:

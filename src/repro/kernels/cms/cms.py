@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ... import kernels
+
 
 def _cms_kernel(idx_ref, mask_ref, out_ref, *, depth: int, block_width: int):
     # idx_ref: (depth, BK) int32 bucket indices; mask_ref: (1, BK) bool
@@ -40,8 +42,8 @@ def _cms_kernel(idx_ref, mask_ref, out_ref, *, depth: int, block_width: int):
 
 
 def cms_update_pallas(indices: jnp.ndarray, mask: jnp.ndarray, width: int, *,
-                      block_keys: int = 1024, block_width: int = 2048,
-                      interpret: bool = False) -> jnp.ndarray:
+                      block_keys: int = 1024, block_width: int = 2048
+                      ) -> jnp.ndarray:
     """(depth, N) bucket indices -> (depth, width) int32 sketch."""
     depth, n = indices.shape
     assert n % block_keys == 0 and width % block_width == 0
@@ -55,5 +57,5 @@ def cms_update_pallas(indices: jnp.ndarray, mask: jnp.ndarray, width: int, *,
         ],
         out_specs=pl.BlockSpec((depth, block_width), lambda w, k: (0, w)),
         out_shape=jax.ShapeDtypeStruct((depth, width), jnp.int32),
-        interpret=interpret,
+        interpret=kernels.use_interpreter(),
     )(indices, mask)

@@ -11,10 +11,9 @@ from .ref import cms_update_ref
 
 
 @functools.partial(jax.jit, static_argnames=("width", "use_kernel",
-                                             "interpret", "block_keys",
-                                             "block_width"))
+                                             "block_keys", "block_width"))
 def cms_update(indices: jnp.ndarray, mask: jnp.ndarray, width: int,
-               use_kernel: bool = True, interpret: bool = True,
+               use_kernel: bool = True,
                block_keys: int = 1024, block_width: int = 2048) -> jnp.ndarray:
     """Build a (depth, width) CMS from (depth, N) bucket indices + (N,) mask."""
     if not use_kernel:
@@ -27,5 +26,4 @@ def cms_update(indices: jnp.ndarray, mask: jnp.ndarray, width: int,
         indices = jnp.pad(indices, ((0, 0), (0, pad)))
         mask = jnp.pad(mask, (0, pad))
     return cms_update_pallas(indices, mask.reshape(1, -1), width,
-                             block_keys=bk, block_width=bw,
-                             interpret=interpret)
+                             block_keys=bk, block_width=bw)

@@ -12,11 +12,11 @@ lane-aligned tiles (last dim a multiple of 128).
 """
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ... import kernels
 from ...core import u64, hashing
 
 
@@ -37,7 +37,7 @@ def _mix_kernel(ahi_ref, alo_ref, ohi_ref, olo_ref):
 
 
 def _launch(kernel, arrays, block_rows: int, block_lanes: int,
-            num_out: int, interpret: bool):
+            num_out: int):
     r, l = arrays[0].shape
     assert r % block_rows == 0 and l % block_lanes == 0
     grid = (r // block_rows, l // block_lanes)
@@ -48,18 +48,16 @@ def _launch(kernel, arrays, block_rows: int, block_lanes: int,
         in_specs=[spec] * len(arrays),
         out_specs=[spec] * num_out,
         out_shape=[jax.ShapeDtypeStruct((r, l), jnp.uint32)] * num_out,
-        interpret=interpret,
+        interpret=kernels.use_interpreter(),
     )(*arrays)
 
 
-def combine64_pallas(ahi, alo, bhi, blo, *, block_rows=8, block_lanes=512,
-                     interpret=False):
+def combine64_pallas(ahi, alo, bhi, blo, *, block_rows=8, block_lanes=512):
     """Order-canonical combine of two u64 key arrays (2-D, tile-aligned)."""
     return _launch(_combine_kernel, [ahi, alo, bhi, blo], block_rows,
-                   block_lanes, 2, interpret)
+                   block_lanes, 2)
 
 
-def mix64_pallas(ahi, alo, *, block_rows=8, block_lanes=512, interpret=False):
+def mix64_pallas(ahi, alo, *, block_rows=8, block_lanes=512):
     """Bulk splitmix64 finalizer over a u64 array (2-D, tile-aligned)."""
-    return _launch(_mix_kernel, [ahi, alo], block_rows, block_lanes, 2,
-                   interpret)
+    return _launch(_mix_kernel, [ahi, alo], block_rows, block_lanes, 2)

@@ -25,9 +25,8 @@ def _tile(x: jnp.ndarray):
     return flat.reshape(-1, _LANES), n
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def combine64(ahi, alo, bhi, blo, use_kernel: bool = True,
-              interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
+def combine64(ahi, alo, bhi, blo, use_kernel: bool = True):
     """Canonical pairwise key combine; shape-preserving over any rank."""
     if not use_kernel:
         return combine64_ref(ahi, alo, bhi, blo)
@@ -36,18 +35,16 @@ def combine64(ahi, alo, bhi, blo, use_kernel: bool = True,
     tb, _ = _tile(alo)
     tc, _ = _tile(bhi)
     td, _ = _tile(blo)
-    hi, lo = combine64_pallas(ta, tb, tc, td, block_rows=_ROWS,
-                              interpret=interpret)
+    hi, lo = combine64_pallas(ta, tb, tc, td, block_rows=_ROWS)
     return hi.reshape(-1)[:n].reshape(shape), lo.reshape(-1)[:n].reshape(shape)
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
-def mix64_bulk(ahi, alo, use_kernel: bool = True, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("use_kernel",))
+def mix64_bulk(ahi, alo, use_kernel: bool = True):
     if not use_kernel:
         return mix64_ref(ahi, alo)
     shape = ahi.shape
     ta, n = _tile(ahi)
     tb, _ = _tile(alo)
-    hi, lo = mix64_pallas(ta, tb, block_rows=_ROWS,
-                          interpret=interpret)
+    hi, lo = mix64_pallas(ta, tb, block_rows=_ROWS)
     return hi.reshape(-1)[:n].reshape(shape), lo.reshape(-1)[:n].reshape(shape)

@@ -18,13 +18,19 @@ never leaves the device:
    the only XLA-side work left is the tiny cross-tile base cumsum and
    ONE scatter into the packed output buffer (memory-bound data
    movement, which stays in XLA by this repo's kernel convention; see
-   ``ops.compact_matched``).
+   ``ops.compact_matched``). The in-tile prefix sum is the radix
+   kernel's triangular-matmul ``lane_prefix`` (Mosaic has no ``cumsum``).
 
 Member gathers (``tokens[a]``) also stay in XLA — the kernel reads each
 pair's already-gathered ``(C, T)`` token stack from HBM exactly once.
 Token/mask stacks arrive transposed to ``(C, T, lanes)`` so the lane
 dimension is the pair axis; ``T`` is padded to a sublane multiple with
 ``mask == 0`` rows, which contribute nothing to any Jaccard term.
+
+Per-tile (1, 128) lane vectors (``valid`` in; ``matched``/``rank``/
+``count`` out) travel as (tiles, 1, 128) arrays so that every block's
+last two dims equal the array's — Mosaic refuses (1, 128) blocks of a
+2-D array.
 
 Grid: (pairs / 128,) over (C, T, 128) column blocks per tile.
 """
@@ -35,6 +41,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ... import kernels
+from ..sort.sort import lane_prefix
 
 _LANES = 128
 # sublane granularity the token axis is padded to (float32/int32 tiling)
@@ -74,31 +83,34 @@ def _match_kernel(ta_ref, ma_ref, tb_ref, mb_ref, valid_ref,
     score = jnp.where(norm > 0, total / jnp.maximum(norm, 1e-6), 0.0)
     matched = (valid_ref[...] != 0) & (score >= threshold)
     mi = matched.astype(jnp.int32)
+    # the MXU wants a full sublane tile: prefix 8 copies, keep row 0
+    mi8 = jnp.broadcast_to(mi, (SUBLANES, _LANES))
+    lane_id = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    tot = lane_prefix(mi8, inclusive_of_all=True)[0:1, :]
     matched_ref[...] = mi
-    rank_ref[...] = jnp.cumsum(mi, axis=1) - mi     # exclusive in-tile rank
-    count_ref[...] = jnp.zeros((1, _LANES), jnp.int32)
-    count_ref[0, 0] = jnp.sum(mi)
+    rank_ref[...] = lane_prefix(mi8)[0:1, :]        # exclusive in-tile rank
+    count_ref[...] = jnp.where(lane_id == 0, tot, 0)
 
 
 def match_score_pallas(ta: jnp.ndarray, ma: jnp.ndarray, tb: jnp.ndarray,
                        mb: jnp.ndarray, valid: jnp.ndarray, *,
-                       weights: tuple, threshold: float,
-                       interpret: bool = False):
-    """(C, T, P) token/mask stacks + (P/128, 128) valid -> fused match.
+                       weights: tuple, threshold: float):
+    """(C, T, P) token/mask stacks + (P/128, 1, 128) valid -> fused match.
 
     ``ta``/``tb`` are uint32 token stacks, ``ma``/``mb``/``valid`` int32
-    0/1 masks. P must divide 128 and T must divide ``SUBLANES`` (ops.py
+    0/1 masks. P must be a multiple of 128 and T of ``SUBLANES`` (ops.py
     pads). Returns int32 ``(matched, rank, count)`` each shaped
-    (P/128, 128); ``count`` carries the tile's matched total in lane 0
-    of each row and zeros beyond (same lane-padding convention as the
-    radix kernel's histogram output).
+    (P/128, 1, 128); ``count`` carries the tile's matched total in lane 0
+    and zeros beyond (the same lane-padding convention as the radix
+    kernel's histogram output). Interpreted iff
+    ``kernels.use_interpreter()``.
     """
     n_cols, t_pad, n_pairs = ta.shape
     assert n_pairs % _LANES == 0 and t_pad % SUBLANES == 0, ta.shape
     grid = (n_pairs // _LANES,)
     col_spec = pl.BlockSpec((n_cols, t_pad, _LANES), lambda g: (0, 0, g))
-    lane_spec = pl.BlockSpec((1, _LANES), lambda g: (g, 0))
-    out = jax.ShapeDtypeStruct((grid[0], _LANES), jnp.int32)
+    lane_spec = pl.BlockSpec((pl.Squeezed(), 1, _LANES), lambda g: (g, 0, 0))
+    out = jax.ShapeDtypeStruct((grid[0], 1, _LANES), jnp.int32)
     return pl.pallas_call(
         functools.partial(_match_kernel, weights=weights,
                           threshold=threshold),
@@ -106,5 +118,5 @@ def match_score_pallas(ta: jnp.ndarray, ma: jnp.ndarray, tb: jnp.ndarray,
         in_specs=[col_spec, col_spec, col_spec, col_spec, lane_spec],
         out_specs=(lane_spec, lane_spec, lane_spec),
         out_shape=(out, out, out),
-        interpret=interpret,
+        interpret=kernels.use_interpreter(),
     )(ta, ma, tb, mb, valid)

@@ -80,10 +80,9 @@ def _round_up(x: int, q: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "chunk", "weights", "threshold", "use_kernel", "interpret"))
+    "chunk", "weights", "threshold", "use_kernel"))
 def _match_chunk(tokens, masks, a, b, base, n_real, *, chunk: int,
-                 weights: tuple, threshold: float, use_kernel: bool,
-                 interpret: bool):
+                 weights: tuple, threshold: float, use_kernel: bool):
     """Score one ``chunk`` of the pair list and emit compaction inputs.
 
     ``base``/``n_real`` are device int32 scalars so any offset reuses one
@@ -112,13 +111,12 @@ def _match_chunk(tokens, masks, a, b, base, n_real, *, chunk: int,
         # masks ride as int32 0/1 (bool tiles are backend-fragile)
         ma = stacked(masks, aa, jnp.int32)
         mb = stacked(masks, bb, jnp.int32)
-        v = valid.astype(jnp.int32).reshape(-1, _LANES)
+        v = valid.astype(jnp.int32).reshape(-1, 1, _LANES)
         m2, r2, c2 = match_score_pallas(ta, ma, tb, mb, v, weights=weights,
-                                        threshold=threshold,
-                                        interpret=interpret)
+                                        threshold=threshold)
         matched = m2.reshape(-1) != 0
         rank = r2.reshape(-1)
-        counts = c2[:, 0]
+        counts = c2[:, 0, 0]
     else:
         score = score_lanes_jnp(tokens, masks, weights, aa, bb)
         matched = valid & (score >= threshold)
@@ -150,7 +148,7 @@ def compact_matched(aa, bb, matched, rank, counts):
 
 def fused_match_pairs(tokens, masks, weights, a, b, *, threshold: float,
                       n_real: int, chunk: int = DEFAULT_CHUNK,
-                      use_kernel: bool = False, interpret: bool = False):
+                      use_kernel: bool = False):
     """Fused match over a device pair list -> compacted device buffers.
 
     Returns ``(ca, cb, count)``, all device-resident: the first ``count``
@@ -173,7 +171,7 @@ def fused_match_pairs(tokens, masks, weights, a, b, *, threshold: float,
         parts.append(_match_chunk(
             tokens, masks, a, b, jax.device_put(np.int32(off)), n_dev,
             chunk=chunk, weights=weights, threshold=threshold,
-            use_kernel=use_kernel, interpret=interpret))
+            use_kernel=use_kernel))
     if len(parts) == 1:
         aa, bb, matched, rank, counts = parts[0]
     else:

@@ -20,6 +20,7 @@ import numpy as np
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ... import kernels
 from ...core import u64
 from ...core.minhash import _MH_SEED
 
@@ -64,8 +65,7 @@ def _minhash_kernel(tokens_ref, mask_ref, addhi_ref, addlo_ref, out_ref, *,
 
 def minhash_pallas(tokens: jnp.ndarray, mask: jnp.ndarray, num_hashes: int,
                    seed: int = _MH_SEED, *, block_rows: int = 256,
-                   block_tokens: int = 128, interpret: bool = False
-                   ) -> jnp.ndarray:
+                   block_tokens: int = 128) -> jnp.ndarray:
     """(R, T) uint32 tokens + mask -> (R, M) uint32 MinHashes.
 
     R must divide block_rows, T must divide block_tokens (ops.py pads).
@@ -87,5 +87,5 @@ def minhash_pallas(tokens: jnp.ndarray, mask: jnp.ndarray, num_hashes: int,
         ],
         out_specs=pl.BlockSpec((block_rows, num_hashes), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, num_hashes), jnp.uint32),
-        interpret=interpret,
+        interpret=kernels.use_interpreter(),
     )(tokens.astype(jnp.uint32), mask, add_hi, add_lo)

@@ -11,16 +11,11 @@ from .ref import minhash_ref
 
 
 @functools.partial(jax.jit, static_argnames=("num_hashes", "use_kernel",
-                                             "interpret", "block_rows",
-                                             "block_tokens"))
+                                             "block_rows", "block_tokens"))
 def minhash(tokens: jnp.ndarray, mask: jnp.ndarray, num_hashes: int,
-            use_kernel: bool = True, interpret: bool = True,
+            use_kernel: bool = True,
             block_rows: int = 256, block_tokens: int = 128) -> jnp.ndarray:
-    """MinHash matrix (R, num_hashes) for padded token sets.
-
-    ``interpret=True`` is the CPU-container default; on real TPU pass
-    ``interpret=False``.
-    """
+    """MinHash matrix (R, num_hashes) for padded token sets."""
     if not use_kernel:
         return minhash_ref(tokens, mask, num_hashes)
     r, t = tokens.shape
@@ -32,5 +27,5 @@ def minhash(tokens: jnp.ndarray, mask: jnp.ndarray, num_hashes: int,
         tokens = jnp.pad(tokens, ((0, pad_r), (0, pad_t)))
         mask = jnp.pad(mask, ((0, pad_r), (0, pad_t)))
     out = minhash_pallas(tokens, mask, num_hashes, block_rows=br,
-                         block_tokens=bt, interpret=interpret)
+                         block_tokens=bt)
     return out[:r]

@@ -91,24 +91,24 @@ def tri_decode_jnp(local: jnp.ndarray, n: jnp.ndarray,
     return i.astype(jnp.int32), j.astype(jnp.int32)
 
 
-def _tri_decode(local, n, steps: int, use_kernel: bool, interpret: bool):
+def _tri_decode(local, n, steps: int, use_kernel: bool):
     if not use_kernel:
         return tri_decode_jnp(local, n, steps)
     flat = local.reshape(-1)
     pad = (-flat.shape[0]) % _TILE
     lp = jnp.pad(flat, (0, pad)).reshape(-1, _LANES)
     np_ = jnp.pad(n.reshape(-1), (0, pad)).reshape(-1, _LANES)
-    i, j = tri_decode_pallas(lp, np_, steps=steps, interpret=interpret)
+    i, j = tri_decode_pallas(lp, np_, steps=steps)
     sl = slice(0, flat.shape[0])
     return i.reshape(-1)[sl].reshape(local.shape), j.reshape(-1)[sl].reshape(local.shape)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("chunk", "steps", "use_kernel", "interpret"))
+                   static_argnames=("chunk", "steps", "use_kernel"))
 def decode_chunk(cum: jnp.ndarray, start: jnp.ndarray, size: jnp.ndarray,
                  members: jnp.ndarray, base: jnp.ndarray, total: jnp.ndarray,
                  *, chunk: int, steps: int = MAX_SEARCH_STEPS,
-                 use_kernel: bool = False, interpret: bool = True):
+                 use_kernel: bool = False):
     """Decode pair slots [base, base+chunk) -> (a, b, src_size, valid).
 
     All CSR inputs are int32 device arrays; ``cum`` has length B+1 with
@@ -128,23 +128,23 @@ def decode_chunk(cum: jnp.ndarray, start: jnp.ndarray, size: jnp.ndarray,
     block = jnp.clip(block, 0, cum.shape[0] - 2)
     local = jnp.where(valid, slots, 0) - cum[block]
     n = size[block]
-    i, j = _tri_decode(local, n, steps, use_kernel, interpret)
+    i, j = _tri_decode(local, n, steps, use_kernel)
     s0 = start[block]
     a = members[s0 + i]
     b = members[s0 + j]
     return (jnp.minimum(a, b), jnp.maximum(a, b), n, valid)
 
 
-@functools.partial(jax.jit, static_argnames=("steps", "use_kernel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("steps", "use_kernel"))
 def decode_block_local(start: jnp.ndarray, size: jnp.ndarray,
                        members: jnp.ndarray, block: jnp.ndarray,
                        local: jnp.ndarray, valid: jnp.ndarray,
                        *, steps: int = MAX_SEARCH_STEPS,
-                       use_kernel: bool = False, interpret: bool = True):
+                       use_kernel: bool = False):
     """Decode pre-split (block, local) slots (sampling fallback path)."""
     block = jnp.clip(block, 0, size.shape[0] - 1)
     n = size[block]
-    i, j = _tri_decode(local, n, steps, use_kernel, interpret)
+    i, j = _tri_decode(local, n, steps, use_kernel)
     s0 = start[block]
     a = members[s0 + i]
     b = members[s0 + j]
@@ -241,7 +241,7 @@ def radix_passes_for(max_rid: int) -> int:
 def dedupe_packed_device(hi: jnp.ndarray, lo: jnp.ndarray,
                          sort_backend: str = "comparator",
                          n_passes: int = sort_ops.MAX_PASSES,
-                         use_kernel: bool = False, interpret: bool = True):
+                         use_kernel: bool = False):
     """Shard-local dedupe of packed sort words: one sort + winner mask.
 
     The device mirror of ``dedupe_packed_host`` for use INSIDE shard_map
@@ -254,8 +254,7 @@ def dedupe_packed_device(hi: jnp.ndarray, lo: jnp.ndarray,
     winner_mask).
     """
     shi, slo = sort_ops.sort_words(hi, lo, backend=sort_backend,
-                                   n_passes=n_passes, use_kernel=use_kernel,
-                                   interpret=interpret)
+                                   n_passes=n_passes, use_kernel=use_kernel)
     # run id = word >> 16 == (a << 23) | b: equal iff hi AND lo>>16 match
     srun = slo >> 16
     live = ~((shi == jnp.uint32(0xFFFFFFFF)) & (slo == jnp.uint32(0xFFFFFFFF)))
@@ -274,12 +273,11 @@ def unpack_words_host(w: np.ndarray) -> Tuple[np.ndarray, np.ndarray, np.ndarray
 
 
 @functools.partial(
-    jax.jit, static_argnames=("sort_backend", "n_passes", "use_kernel",
-                              "interpret"))
+    jax.jit, static_argnames=("sort_backend", "n_passes", "use_kernel"))
 def dedupe_device(a: jnp.ndarray, b: jnp.ndarray, src_size: jnp.ndarray,
                   valid: jnp.ndarray, *, sort_backend: str = "comparator",
                   n_passes: int = sort_ops.MAX_PASSES,
-                  use_kernel: bool = False, interpret: bool = True):
+                  use_kernel: bool = False):
     """Device sort (a, b, size desc); mark each pair's largest-block winner.
 
     ``sort_backend="comparator"`` is the general-rid path (no
@@ -294,7 +292,7 @@ def dedupe_device(a: jnp.ndarray, b: jnp.ndarray, src_size: jnp.ndarray,
         hi, lo = pack_sort_words(a, b, src_size, valid)
         shi, slo, winner = dedupe_packed_device(
             hi, lo, sort_backend="radix", n_passes=n_passes,
-            use_kernel=use_kernel, interpret=interpret)
+            use_kernel=use_kernel)
         # unpack the winner words back to int32 triplets on device
         ua = (shi >> 7).astype(jnp.int32)
         ub = (((shi & jnp.uint32(0x7F)) << 16) | (slo >> 16)).astype(jnp.int32)

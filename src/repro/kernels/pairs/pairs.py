@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ... import kernels
+
 # Largest block size whose row products fit uint32 (see module docstring).
 MAX_BLOCK_N = 65535
 # ceil(log2(MAX_BLOCK_N - 1)) = 16 candidate-row halvings always suffice;
@@ -59,13 +61,13 @@ def _tri_decode_kernel(local_ref, n_ref, i_ref, j_ref, *, steps: int):
 
 
 def tri_decode_pallas(local: jnp.ndarray, n: jnp.ndarray, *,
-                      steps: int = MAX_SEARCH_STEPS, block_rows: int = 8,
-                      interpret: bool = False):
+                      steps: int = MAX_SEARCH_STEPS, block_rows: int = 8):
     """(R, 128) int32 local slot + block size -> (i, j) int32, i < j.
 
     R must divide block_rows (ops.py pads). ``steps`` must cover the
     largest block present (``search_steps_for``). Lanes with ``n < 2``
-    produce garbage and must be masked by the caller.
+    produce garbage and must be masked by the caller. Interpreted iff
+    ``kernels.use_interpreter()``.
     """
     rows, lanes = local.shape
     assert lanes == 128 and rows % block_rows == 0, (rows, lanes)
@@ -78,5 +80,5 @@ def tri_decode_pallas(local: jnp.ndarray, n: jnp.ndarray, *,
         in_specs=[spec, spec],
         out_specs=(spec, spec),
         out_shape=(out, out),
-        interpret=interpret,
+        interpret=kernels.use_interpreter(),
     )(local.astype(jnp.int32), n.astype(jnp.int32))

@@ -11,8 +11,8 @@ site routes through:
   Each pass computes per-element stable positions (digit base + rank
   within digit) and applies ONE scatter; ``use_kernel=True`` runs the
   histogram/rank step in the Pallas kernel (``sort.radix_pass_pallas``,
-  interpret mode on CPU), otherwise an equivalent fused-jnp one-hot
-  cumsum mirror. Both are bit-identical to the comparator path on any
+  interpreted on the CPU backend only), otherwise an equivalent
+  fused-jnp one-hot cumsum mirror. Both are bit-identical to the comparator path on any
   input (a sorted multiset is unique), which the parity suite asserts.
 
 The pass count is STATIC: callers bound the significant word bits (e.g.
@@ -50,14 +50,17 @@ def _rank_pass_jnp(d: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Stable in-digit rank + per-digit counts via one-hot cumsum.
 
     The jnp mirror of the Pallas histogram/rank kernel, whole-array (no
-    tiling): rank[i] = #(j < i with d[j] == d[i]).
+    tiling): rank[i] = #(j < i with d[j] == d[i]). The one-hot is
+    digit-major, (RADIX, n): with the RADIX axis minor, TPU layouts pad
+    it to 128 lanes (8x the bytes), which at a 20M-slot pair budget no
+    longer fits a 16 GB v5e.
     """
-    onehot = (d[:, None]
-              == jnp.arange(RADIX, dtype=d.dtype)[None, :]).astype(jnp.int32)
-    incl = jnp.cumsum(onehot, axis=0)
-    rank = jnp.take_along_axis(incl, d.astype(jnp.int32)[:, None],
-                               axis=1)[:, 0] - 1
-    return rank, incl[-1]
+    onehot = (d[None, :]
+              == jnp.arange(RADIX, dtype=d.dtype)[:, None]).astype(jnp.int32)
+    incl = jnp.cumsum(onehot, axis=1)
+    rank = jnp.take_along_axis(incl, d.astype(jnp.int32)[None, :],
+                               axis=0)[0] - 1
+    return rank, incl[:, -1]
 
 
 def _scatter_pass(hi, lo, pos):
@@ -76,7 +79,7 @@ def _radix_sort_jnp(hi, lo, n_passes: int):
     return hi, lo
 
 
-def _radix_sort_kernel(hi, lo, n_passes: int, interpret: bool):
+def _radix_sort_kernel(hi, lo, n_passes: int):
     n = hi.shape[0]
     pad = (-n) % _TILE
     sentinel = jnp.uint32(0xFFFFFFFF)
@@ -90,8 +93,8 @@ def _radix_sort_kernel(hi, lo, n_passes: int, interpret: bool):
     for p in range(n_passes):
         rank, hist = radix_pass_pallas(hi.reshape(-1, _LANES),
                                        lo.reshape(-1, _LANES),
-                                       p=p, interpret=interpret)
-        hist = hist[:, :RADIX]                       # (n_tiles, RADIX)
+                                       p=p)
+        hist = hist[:, 0, :RADIX]                    # (n_tiles, RADIX)
         # base[d, t] = all counts of digits < d + counts of d in tiles < t
         flat = hist.T.reshape(-1)                    # digit-major
         base = (jnp.cumsum(flat) - flat).reshape(RADIX, n_tiles)
@@ -103,13 +106,13 @@ def _radix_sort_kernel(hi, lo, n_passes: int, interpret: bool):
 
 def sort_words(hi: jnp.ndarray, lo: jnp.ndarray, *,
                backend: str = "comparator", n_passes: int = MAX_PASSES,
-               use_kernel: bool = False, interpret: bool = True):
+               use_kernel: bool = False):
     """Sort u64 words (uint32 limb pairs) ascending; the one dedupe sort.
 
     Not jitted — traces into the caller (jit or shard_map). ``n_passes``
     must cover every significant bit of the valid words (sentinels are
     safe from ``MIN_PASSES`` up, see module docstring); ``backend``,
-    ``n_passes``, ``use_kernel``, ``interpret`` must be static under the
+    ``n_passes`` and ``use_kernel`` must be static under the
     caller's jit.
     """
     if backend not in SORT_BACKENDS:
@@ -122,15 +125,14 @@ def sort_words(hi: jnp.ndarray, lo: jnp.ndarray, *,
     if hi.shape[0] == 0:
         return hi, lo
     if use_kernel:
-        return _radix_sort_kernel(hi, lo, n_passes, interpret)
+        return _radix_sort_kernel(hi, lo, n_passes)
     return _radix_sort_jnp(hi, lo, n_passes)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_passes", "use_kernel", "interpret"))
+                   static_argnames=("n_passes", "use_kernel"))
 def radix_sort_words(hi: jnp.ndarray, lo: jnp.ndarray, *,
-                     n_passes: int = MAX_PASSES, use_kernel: bool = False,
-                     interpret: bool = True):
+                     n_passes: int = MAX_PASSES, use_kernel: bool = False):
     """Jitted standalone radix sort (bench / direct test entry point)."""
     return sort_words(hi, lo, backend="radix", n_passes=n_passes,
-                      use_kernel=use_kernel, interpret=interpret)
+                      use_kernel=use_kernel)

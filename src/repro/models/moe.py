@@ -22,7 +22,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .config import ModelConfig
@@ -235,14 +234,14 @@ def moe_apply(p, x, cfg: ModelConfig):
             dropped = jax.lax.psum(n_drop_route + n_drop_cap, ep_axis)
             return out.reshape(bl, sl, d), aux, dropped
 
-        out, aux, dropped = shard_map(
+        out, aux, dropped = jax.shard_map(
             body_a2a if use_a2a else body, mesh=mesh,
             in_specs=(P(batch_axis, None, None), P(),
                       P(ep_axis, None, ff_axis),
                       P(ep_axis, None, ff_axis),
                       P(ep_axis, ff_axis, None)),
             out_specs=(P(batch_axis, None, None), P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(x, router_w, w_gate, w_up, w_down)
 
     if cfg.moe_shared_experts:

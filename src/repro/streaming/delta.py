@@ -12,8 +12,8 @@ can have changed a decision**, level by level:
    heuristic must match the batch path bit-for-bit,
 3. apply keep-bit flips to the key table (exact count ±1, fingerprint
    XOR — XOR is its own inverse, so retraction is exact),
-4. re-run the shared jitted ``hdb.survivor_reps`` duplicate-block dedupe
-   over the over-sized key-table slice,
+4. re-run the shared ``hdb.dedupe_oversized_reps`` duplicate-block
+   dedupe over the over-sized key-table slice,
 5. refresh accept/survive bits for entries whose key's exact size or
    survivorship changed; rows whose surviving-key set (or its sizes)
    changed are *dirty* and get re-intersected through the shared jitted
@@ -358,20 +358,10 @@ class DeltaBlocker:
         n_over = len(o_key)
         surv_flags = np.zeros(n_over, bool)
         if n_over:
-            p = _pow2(n_over, floor=64)
-            xhi = np.full(p, _SENT32, np.uint32)
-            xlo = np.full(p, _SENT32, np.uint32)
-            sz = np.full(p, INT32_MAX, np.int32)
-            khi = np.full(p, _SENT32, np.uint32)
-            klo = np.full(p, _SENT32, np.uint32)
             fhi, flo = unpack_key64(o_fp)
-            xhi[:n_over], xlo[:n_over] = fhi, flo
-            sz[:n_over] = o_cnt.astype(np.int32)
-            khi[:n_over], klo[:n_over] = unpack_key64(o_key)
-            _, _, surv = hdb_mod.survivor_reps(
-                jnp.asarray(xhi), jnp.asarray(xlo), jnp.asarray(sz),
-                jnp.asarray(khi), jnp.asarray(klo))
-            surv_flags = np.asarray(surv)[:n_over]
+            khi, klo = unpack_key64(o_key)
+            _, surv_flags = hdb_mod.dedupe_oversized_reps(
+                fhi, flo, o_cnt.astype(np.int32), khi, klo)
         # set_survivors runs even with no over-keys: stale flags from the
         # previous ingest must clear (on every shard of a sharded store)
         sv_changed = state.set_survivors(o_key, surv_flags)

@@ -75,7 +75,6 @@ def _make_keytab_exchange(mesh, axes: Tuple[str, ...], n_shards: int,
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..core import hashing, u64
@@ -92,11 +91,11 @@ def _make_keytab_exchange(mesh, axes: Tuple[str, ...], n_shards: int,
             axes, bhi, blo, bcnt, bfhi, bflo)
         return bhi, blo, bcnt, bfhi, bflo, jax.lax.psum(ovf, axes)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axes),) * 5,
         out_specs=(P(axes, None),) * 5 + (P(),),
-        check_rep=False))
+        check_vma=False))
 
 
 class ShardRouter:

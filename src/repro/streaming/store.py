@@ -275,7 +275,7 @@ class LevelKeys:
         """(key, count, fingerprint) of over-sized table rows, key-sorted.
 
         Key order is part of the bit-identity contract: the duplicate-
-        block survivor pass feeds these to ``hdb.survivor_reps`` and must
+        block survivor pass feeds these to ``hdb.dedupe_oversized_reps`` and must
         see the same order regardless of how the key space is sharded.
         """
         over = self.tab_cnt > max_block_size

@@ -78,6 +78,34 @@ def test_r001_quiet_on_host_side_numpy():
     assert _live(GOOD_R001, select=["R001"]) == []
 
 
+BAD_R001_SHARD_MAP = """
+import jax
+import numpy as np
+from jax.sharding import PartitionSpec as P
+
+def local(x):
+    return np.asarray(x)
+
+step = jax.jit(jax.shard_map(local, mesh=None, in_specs=P("data"),
+                             out_specs=P("data"), check_vma=False))
+"""
+
+
+def test_r001_fires_inside_jax_shard_map():
+    findings = _live(BAD_R001_SHARD_MAP, select=["R001"])
+    assert _rules_of(findings) == {"R001"}
+
+
+def test_shard_map_bodies_of_distributed_are_jit_reachable():
+    from repro.analysis.engine import _parse_context
+    path = os.path.join(SRC, "core", "distributed.py")
+    with open(path) as f:
+        ctx, errors = _parse_context(f.read(), path)
+    assert not errors
+    assert {"local_step", "local_round", "local_dedupe",
+            "local_decode"} <= ctx.jit_reachable
+
+
 # ---------------------------------------------------------------------------
 # R002 dtype-contract drift
 # ---------------------------------------------------------------------------

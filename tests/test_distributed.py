@@ -16,7 +16,8 @@ SHARD_WORKER = os.path.join(os.path.dirname(__file__), "_shard_worker.py")
 def _run(mesh_kind, worker=WORKER):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env.pop("JAX_PLATFORMS", None)
+    # the 8 devices are emulated CPU hosts: the child must never take a chip
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, worker, mesh_kind],
         capture_output=True, text=True, timeout=900, env=env)

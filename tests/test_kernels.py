@@ -28,7 +28,7 @@ def test_minhash_kernel_matches_ref(r, t, m):
     tokens = jnp.asarray(rng.integers(0, 1 << 32, (r, t), dtype=np.uint64)
                          .astype(np.uint32))
     mask = jnp.asarray(rng.random((r, t)) < 0.8)
-    got = minhash(tokens, mask, m, use_kernel=True, interpret=True)
+    got = minhash(tokens, mask, m, use_kernel=True)
     want = minhash_ref(tokens, mask, m)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -45,7 +45,7 @@ def test_minhash_kernel_mask_edge_cases(mask_kind):
     else:
         mask = jnp.asarray(np.repeat([[True], [False]], [16, 16], axis=0)
                            .reshape(32, 1) * np.ones((1, 16), bool))
-    got = minhash(tokens, mask, 8, use_kernel=True, interpret=True)
+    got = minhash(tokens, mask, 8, use_kernel=True)
     want = minhash_ref(tokens, mask, 8)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -60,7 +60,7 @@ def test_combine64_kernel_matches_ref(shape):
     mk = lambda: jnp.asarray(rng.integers(0, 1 << 32, shape, dtype=np.uint64)
                              .astype(np.uint32))
     ahi, alo, bhi, blo = mk(), mk(), mk(), mk()
-    ghi, glo = combine64(ahi, alo, bhi, blo, use_kernel=True, interpret=True)
+    ghi, glo = combine64(ahi, alo, bhi, blo, use_kernel=True)
     whi, wlo = combine64_ref(ahi, alo, bhi, blo)
     np.testing.assert_array_equal(np.asarray(ghi), np.asarray(whi))
     np.testing.assert_array_equal(np.asarray(glo), np.asarray(wlo))
@@ -72,8 +72,8 @@ def test_combine64_is_symmetric_under_swap():
     mk = lambda: jnp.asarray(rng.integers(0, 1 << 32, (512,), dtype=np.uint64)
                              .astype(np.uint32))
     ahi, alo, bhi, blo = mk(), mk(), mk(), mk()
-    h1 = combine64(ahi, alo, bhi, blo, use_kernel=True, interpret=True)
-    h2 = combine64(bhi, blo, ahi, alo, use_kernel=True, interpret=True)
+    h1 = combine64(ahi, alo, bhi, blo, use_kernel=True)
+    h2 = combine64(bhi, blo, ahi, alo, use_kernel=True)
     np.testing.assert_array_equal(np.asarray(h1[0]), np.asarray(h2[0]))
     np.testing.assert_array_equal(np.asarray(h1[1]), np.asarray(h2[1]))
 
@@ -83,8 +83,7 @@ def test_mix64_bulk_matches_ref_and_python(n):
     rng = np.random.default_rng(n)
     vals = rng.integers(0, (1 << 64) - 1, n, dtype=np.uint64)
     packed = jnp.asarray(hashing.np_to_u64_arrays(vals))
-    ghi, glo = mix64_bulk(packed[..., 0], packed[..., 1], use_kernel=True,
-                          interpret=True)
+    ghi, glo = mix64_bulk(packed[..., 0], packed[..., 1], use_kernel=True)
     got = (np.asarray(ghi).astype(np.uint64) << np.uint64(32)) | np.asarray(glo)
     want = np.asarray([hashing.np_mix64(int(v)) for v in vals], np.uint64)
     np.testing.assert_array_equal(got, want)
@@ -104,7 +103,7 @@ def test_cms_kernel_matches_ref(depth, n, width):
     rng = np.random.default_rng(depth * n)
     idx = jnp.asarray(rng.integers(0, width, (depth, n)), jnp.int32)
     mask = jnp.asarray(rng.random(n) < 0.7)
-    got = cms_update(idx, mask, width, use_kernel=True, interpret=True,
+    got = cms_update(idx, mask, width, use_kernel=True,
                      block_keys=256, block_width=1024)
     want = cms_update_ref(idx, mask, width)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -120,6 +119,6 @@ def test_cms_kernel_plugs_into_sketch_queries():
     mask = jnp.ones(len(vals), bool)
     idx = sketches.cms_indices(cfg, key)
     sk_kernel = cms_update(idx, mask, cfg.width, use_kernel=True,
-                           interpret=True, block_keys=512, block_width=1024)
+                           block_keys=512, block_width=1024)
     sk_ref = sketches.cms_build(cfg, key, mask)
     np.testing.assert_array_equal(np.asarray(sk_kernel), np.asarray(sk_ref))

@@ -174,8 +174,7 @@ def test_tri_decode_pallas_matches_jnp_dense():
     ji, jj = jax.jit(tri_decode_jnp, static_argnames=("steps",))(
         jnp.asarray(t32), jnp.asarray(n32))
     pi, pj = tri_decode_pallas(jnp.asarray(t32.reshape(-1, 128)),
-                               jnp.asarray(n32.reshape(-1, 128)),
-                               interpret=True)
+                               jnp.asarray(n32.reshape(-1, 128)))
     np.testing.assert_array_equal(np.asarray(pi).reshape(-1), np.asarray(ji))
     np.testing.assert_array_equal(np.asarray(pj).reshape(-1), np.asarray(jj))
 

@@ -41,7 +41,7 @@ def _join(hi, lo):
 def _radix(w, use_kernel=False, n_passes=ksort.MAX_PASSES):
     hi, lo = _limbs(w)
     shi, slo = ksort.radix_sort_words(hi, lo, n_passes=n_passes,
-                                      use_kernel=use_kernel, interpret=True)
+                                      use_kernel=use_kernel)
     return _join(shi, slo)
 
 
@@ -143,9 +143,9 @@ def test_radix_pass_histogram_and_rank():
     hi = (w >> np.uint64(32)).astype(np.uint32).reshape(-1, 128)
     lo = (w & np.uint64(0xFFFFFFFF)).astype(np.uint32).reshape(-1, 128)
     rank, hist = ksort.radix_pass_pallas(jnp.asarray(hi), jnp.asarray(lo),
-                                         p=3, interpret=True)
+                                         p=3)
     rank = np.asarray(rank).reshape(-1)
-    hist = np.asarray(hist)[:, :ksort.RADIX]
+    hist = np.asarray(hist)[:, 0, :ksort.RADIX]
     d = ((w >> np.uint64(3 * ksort.RADIX_BITS))
          & np.uint64(ksort.RADIX - 1)).astype(np.int64)
     tile = np.arange(n) // 1024
