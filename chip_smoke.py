@@ -138,7 +138,7 @@ def phase_batch(sizes, seed):
     log("  check: device PairSet == numpy reference")
 
     t0 = time.perf_counter()
-    n_host, label, survivors, _ = pipeline.match_and_cluster(
+    n_host, label, survivors, _, _ = pipeline.match_and_cluster(
         corpus, got, match_backend="host")
     log(f"  host back half: matched_pairs={n_host} "
         f"components={len(survivors)} ({time.perf_counter() - t0!r}s)")

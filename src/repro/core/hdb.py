@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import u64, hashing, segments, sketches
+from .. import obs
 from .u64 import U64
 
 INT32_MAX = np.iinfo(np.int32).max
@@ -363,15 +364,18 @@ def hdb_iteration(cfg: HDBConfig, keys_packed: jnp.ndarray, valid: jnp.ndarray,
     representatives, whose survivor flags go back as one fixed
     ``rep_capacity`` mask so the second step compiles once per key width.
     """
-    rough, counted, reps, n_reps, rep_overflow = _count_step(
-        cfg, keys_packed, valid, psize)
-    m = min(int(n_reps), cfg.rep_capacity)
-    n_dup, survivor = dedupe_oversized_reps(
-        *(np.asarray(r)[:m] for r in reps))
-    flags = np.zeros(cfg.rep_capacity, bool)
-    flags[:m] = survivor
-    accepted, new_state, stats = _intersect_step(
-        cfg, keys_packed, valid, rough, counted, jax.device_put(flags))
+    with obs.span("repro.hdb.count"):
+        rough, counted, reps, n_reps, rep_overflow = _count_step(
+            cfg, keys_packed, valid, psize)
+        m = min(int(n_reps), cfg.rep_capacity)
+        reps = [np.asarray(r)[:m] for r in reps]
+    with obs.span("repro.hdb.reps"):
+        n_dup, survivor = dedupe_oversized_reps(*reps)
+        flags = np.zeros(cfg.rep_capacity, bool)
+        flags[:m] = survivor
+    with obs.span("repro.hdb.intersect"):
+        accepted, new_state, stats = _intersect_step(
+            cfg, keys_packed, valid, rough, counted, jax.device_put(flags))
     stats.update(n_duplicate_blocks=n_dup,
                  n_surviving_oversized=int(survivor.sum()),
                  rep_overflow=int(rep_overflow))
@@ -404,15 +408,23 @@ def hashed_dynamic_blocking(
     acc_lo: List[np.ndarray] = []
     all_stats: List[IterationStats] = []
     for it in range(cfg.max_iterations):
-        accepted, (new_keys, new_valid, new_psize), stats = hdb_iteration(
-            cfg, keys_packed, valid, psize)
-        acc_np = np.asarray(accepted)
-        ridx, kidx = np.nonzero(acc_np)
-        keys_np = np.asarray(keys_packed)
-        acc_rid.append(ridx.astype(np.int64))
-        acc_hi.append(keys_np[ridx, kidx, 0])
-        acc_lo.append(keys_np[ridx, kidx, 1])
-        st = IterationStats(iteration=it, **{k: int(v) for k, v in stats.items()})
+        with obs.span("repro.hdb.iteration", iteration=it):
+            accepted, (new_keys, new_valid, new_psize), stats = hdb_iteration(
+                cfg, keys_packed, valid, psize)
+            with obs.span("repro.hdb.accept"):
+                acc_np = np.asarray(accepted)
+                ridx, kidx = np.nonzero(acc_np)
+                keys_np = np.asarray(keys_packed)
+                acc_rid.append(ridx.astype(np.int64))
+                acc_hi.append(keys_np[ridx, kidx, 0])
+                acc_lo.append(keys_np[ridx, kidx, 1])
+                st = IterationStats(iteration=it, **{k: int(v) for k, v in
+                                                     stats.items()})
+            obs.mark("repro.hdb.iteration.counts",
+                     slots=valid.shape[0] * valid.shape[1],
+                     live=st.n_live_keys,
+                     reps=st.n_duplicate_blocks + st.n_surviving_oversized,
+                     surviving=st.n_surviving_oversized)
         all_stats.append(st)
         logger.log(logging.INFO if verbose else logging.DEBUG,
                    "[hdb] iter=%d %s", it, st)

@@ -69,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .hdb import BlockingResult
+from .. import obs
 from ..kernels import pairs as pairs_kernels
 from ..kernels.pairs import ref as pairs_ref
 
@@ -346,18 +347,12 @@ def resolve_sort_backend(sort_backend: str, blocks: Blocks) -> str:
     return sort_backend
 
 
-def _dedupe_device(blocks: Blocks, slots: Optional[np.ndarray], total: int,
-                   chunk_pairs: int, use_kernel: bool,
-                   sort_backend: str = "auto") -> Tuple[np.ndarray, ...]:
-    """Device engine: chunked slot decode + one sort-dedupe pass.
-
-    The dedupe sort strategy comes from ``resolve_sort_backend``:
-    ``"auto"`` packs the words on device and sorts with ``np.sort`` on
-    the CPU backend (host == device memory there, and numpy's u64 sort
-    is ~40x faster than XLA CPU's comparator sort) and radix-sorts on
-    device elsewhere; ``"comparator"``/``"radix"`` force the device sort
-    flavor (useful to exercise and benchmark either on any platform).
-    """
+def _decode_device(blocks: Blocks, slots: Optional[np.ndarray], total: int,
+                   chunk_pairs: int, use_kernel: bool) -> Tuple[list, ...]:
+    """Device slot decode in fixed-shape chunks: every slot of
+    ``[0, total)`` (exact path) or the sampled ``slots``. Returns the
+    chunks' ``a``, ``b``, ``size`` and ``valid`` device arrays, as four
+    lists."""
     # host-side casts + explicit uploads: dtype-coercing jnp.asarray and
     # jnp.int32(py_scalar) are implicit host->device transfers (rejected
     # under jax.transfer_guard("disallow") — repro.analysis R001)
@@ -399,6 +394,21 @@ def _dedupe_device(blocks: Blocks, slots: Optional[np.ndarray], total: int,
                 jnp.asarray(local[sl]), jnp.asarray(valid[sl]),
                 steps=steps, use_kernel=use_kernel)
             out_a.append(a); out_b.append(b); out_s.append(s); out_v.append(v)
+    return out_a, out_b, out_s, out_v
+
+
+def _sort_device(blocks: Blocks, decoded: Tuple[list, ...], use_kernel: bool,
+                 sort_backend: str = "auto") -> Tuple[np.ndarray, ...]:
+    """One sort-dedupe pass over the chunks ``_decode_device`` returned.
+
+    The dedupe sort strategy comes from ``resolve_sort_backend``:
+    ``"auto"`` packs the words on device and sorts with ``np.sort`` on
+    the CPU backend (host == device memory there, and numpy's u64 sort
+    is ~40x faster than XLA CPU's comparator sort) and radix-sorts on
+    device elsewhere; ``"comparator"``/``"radix"`` force the device sort
+    flavor (useful to exercise and benchmark either on any platform).
+    """
+    out_a, out_b, out_s, out_v = decoded
     if not out_a:
         z = np.zeros((0,), np.int64)
         return z, z, z, None
@@ -479,15 +489,19 @@ def dedupe_pairs(blocks: Blocks, budget: int = 50_000_000,
             chunk_per_shard=chunk_pairs, route_slack=route_slack,
             sample_seed=sample_seed, sort_backend=sort_backend)
     exact = total <= budget
-    slots = None if exact else _sample_slots(total, budget, sample_seed)
     backend = resolve_backend(backend, blocks, budget)
     if backend == "numpy":
+        slots = None if exact else _sample_slots(total, budget, sample_seed)
         a, b, s = _dedupe_numpy(blocks, slots)
-        dev = None
-    else:
-        a, b, s, dev = _dedupe_device(blocks, slots, total, chunk_pairs,
-                                      use_kernel=(backend == "pallas"),
-                                      sort_backend=sort_backend)
+        return PairSet(a, b, s, exact, total)
+    use_kernel = backend == "pallas"
+    with obs.span("repro.pairs.decode"):
+        slots = None if exact else _sample_slots(total, budget, sample_seed)
+        decoded = _decode_device(blocks, slots, total, chunk_pairs,
+                                 use_kernel)
+    with obs.span("repro.pairs.sort", slots=min(total, budget)):
+        a, b, s, dev = _sort_device(blocks, decoded, use_kernel, sort_backend)
+    obs.mark("repro.pairs.sort.counts", pairs=len(a))
     return PairSet(a, b, s, exact, total,
                    device_a=None if dev is None else dev[0],
                    device_b=None if dev is None else dev[1])

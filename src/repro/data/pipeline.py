@@ -31,6 +31,7 @@ import numpy as np
 from ..core import blocks as blocks_mod
 from ..core import hdb as hdb_mod
 from ..core import pairs as pairs_mod
+from .. import obs
 from . import components, matcher
 from .synthetic import Corpus
 
@@ -72,33 +73,36 @@ def dedup_corpus(corpus: Corpus,
                  match_backend: str = "auto",
                  cc_max_rounds: int = 64) -> DedupReport:
     n = corpus.num_records
-    t0 = time.perf_counter()
-    keys, valid = blocks_mod.build_keys(corpus.columns, corpus.blocking)
-    if blocker == "hdb":
-        result = hdb_mod.hashed_dynamic_blocking(keys, valid, cfg, verbose=verbose)
-    elif blocker == "threshold":
-        from ..core.baselines import threshold_blocking
-        result = threshold_blocking(keys, valid, cfg.max_block_size)
-    else:
-        raise ValueError(blocker)
-    blk = pairs_mod.build_blocks(result)
-    pset = pairs_mod.dedupe_pairs(blk, budget=pair_budget)
-    # feed the matcher the device pair buffer directly (no host round trip
-    # of the pair list when the device dedupe path produced it)
-    _sync(pset.pair_buffers())
-    t1 = time.perf_counter()
-    num_matched, label, survivors, t2 = match_and_cluster(
-        corpus, pset, match_cfg, match_backend, cc_max_rounds)
-    t3 = time.perf_counter()
+    with obs.span("repro.pipeline.blocking") as blocking:
+        with obs.span("repro.pipeline.keys"):
+            keys, valid = blocks_mod.build_keys(corpus.columns,
+                                                corpus.blocking)
+        if blocker == "hdb":
+            result = hdb_mod.hashed_dynamic_blocking(keys, valid, cfg,
+                                                     verbose=verbose)
+        elif blocker == "threshold":
+            from ..core.baselines import threshold_blocking
+            result = threshold_blocking(keys, valid, cfg.max_block_size)
+        else:
+            raise ValueError(blocker)
+        with obs.span("repro.pairs.blocks"):
+            blk = pairs_mod.build_blocks(result)
+        pset = pairs_mod.dedupe_pairs(blk, budget=pair_budget)
+        # feed the matcher the device pair buffer directly (no host round
+        # trip of the pair list when the device dedupe path produced it)
+        _sync(pset.pair_buffers())
+    num_matched, label, survivors, matching_s, partition_s = \
+        match_and_cluster(corpus, pset, match_cfg, match_backend,
+                          cc_max_rounds)
     return DedupReport(
         num_records=n,
         num_candidate_pairs=len(pset.a),
         num_matched_pairs=num_matched,
         num_components=len(survivors),
         num_survivors=len(survivors),
-        blocking_seconds=t1 - t0,
-        matching_seconds=t2 - t1,
-        partition_seconds=t3 - t2,
+        blocking_seconds=blocking.seconds,
+        matching_seconds=matching_s,
+        partition_seconds=partition_s,
         survivors=survivors,
         component_of=label,
         blocks=blk,
@@ -111,41 +115,53 @@ def match_and_cluster(corpus: Corpus, pset: pairs_mod.PairSet,
                       match_backend: str = "auto", cc_max_rounds: int = 64):
     """The batch back half: match the candidate pairs, then cluster.
 
-    Returns ``(num_matched, component_of, survivors, t_matched)``, where
-    ``t_matched`` is the ``perf_counter`` reading between the two stages.
+    Returns ``(num_matched, component_of, survivors, matching_seconds,
+    partition_seconds)``; the two times are those of the
+    ``repro.pipeline.match`` and ``repro.pipeline.partition`` spans.
     """
     n = corpus.num_records
-    backend = ("host" if match_backend == "host"
-               else matcher.resolve_match_backend(match_backend))
-    dev_a, dev_b = pset.pair_buffers()
-    if backend == "host":
-        # parity baseline: scores + matched mask land host-side, the
-        # matched pair list is gathered in numpy and re-uploaded for CC
-        matched = matcher.match_pairs(corpus.columns, dev_a, dev_b, match_cfg)
-        ma, mb = pset.a[matched], pset.b[matched]
-        num_matched = int(matched.sum())
-        t_matched = time.perf_counter()
-        label = components.connected_components(n, ma, mb,
-                                                max_rounds=cc_max_rounds)
-        # canonical survivor = min record id per component == the label
-        survivors = np.unique(label)
-    else:
-        # fused path: matched pairs stay device-resident end to end —
-        # the compacted (0,0)-padded buffer flows straight into CC and
-        # only labels/survivors/counters ever cross to the host
-        ca, cb, cnt = matcher.match_compact(corpus.columns, dev_a, dev_b,
-                                            match_cfg, backend=backend)
-        _sync(ca, cb, cnt)
-        t_matched = time.perf_counter()
-        label_d, surv_d, n_surv, converged, _ = components.cluster_pairs_device(
-            n, ca, cb, max_rounds=cc_max_rounds)
-        _sync(label_d, surv_d)
-        if not bool(np.asarray(converged)):
-            components._warn_truncated(cc_max_rounds)
-        num_matched = int(np.asarray(cnt))
-        label = np.asarray(label_d)[:n].astype(np.int64)
-        survivors = np.asarray(surv_d)[:int(np.asarray(n_surv))].astype(np.int64)
-    return num_matched, label, survivors, t_matched
+    with obs.span("repro.pipeline.match") as matching:
+        backend = ("host" if match_backend == "host"
+                   else matcher.resolve_match_backend(match_backend))
+        dev_a, dev_b = pset.pair_buffers()
+        if backend == "host":
+            # parity baseline: scores + matched mask land host-side, the
+            # matched pair list is gathered in numpy and re-uploaded for CC
+            matched = matcher.match_pairs(corpus.columns, dev_a, dev_b,
+                                          match_cfg)
+            ma, mb = pset.a[matched], pset.b[matched]
+            num_matched = int(matched.sum())
+        else:
+            # fused path: matched pairs stay device-resident end to end —
+            # the compacted (0,0)-padded buffer flows straight into CC and
+            # only labels/survivors/counters ever cross to the host
+            with obs.span("repro.match.compact"):
+                ca, cb, cnt = matcher.match_compact(
+                    corpus.columns, dev_a, dev_b, match_cfg, backend=backend)
+            _sync(ca, cb, cnt)
+    with obs.span("repro.pipeline.partition") as partition:
+        if backend == "host":
+            label = components.connected_components(
+                n, ma, mb, max_rounds=cc_max_rounds)
+            # canonical survivor = min record id per component == the label
+            survivors = np.unique(label)
+        else:
+            with obs.span("repro.components.cc"):
+                label_d, surv_d, n_surv, converged, rounds = \
+                    components.cluster_pairs_device(
+                        n, ca, cb, max_rounds=cc_max_rounds)
+                _sync(label_d, surv_d)
+            converged, rounds = jax.device_get((converged, rounds))
+            obs.mark("repro.components.cc.counts", rounds=int(rounds),
+                     converged=bool(converged))
+            if not converged:
+                components._warn_truncated(cc_max_rounds)
+            num_matched = int(np.asarray(cnt))
+            label = np.asarray(label_d)[:n].astype(np.int64)
+            survivors = np.asarray(surv_d)[:int(np.asarray(n_surv))].astype(
+                np.int64)
+    return (num_matched, label, survivors, matching.seconds,
+            partition.seconds)
 
 
 class DedupPipeline:

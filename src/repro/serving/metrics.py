@@ -23,7 +23,7 @@ class Counter:
     def __init__(self):
         self.value = 0
 
-    def inc(self, n: int = 1) -> None:
+    def inc(self, n: float = 1) -> None:
         self.value += n
 
 

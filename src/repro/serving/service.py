@@ -40,6 +40,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from .. import obs
 from ..core import hdb as hdb_mod
 from ..data.components import ClusterResult, cluster_edges
 from ..streaming.delta import DeltaBlocker, IngestReport, QueryResult
@@ -273,10 +274,25 @@ class DedupeService:
 
     def step(self) -> None:
         """Shed expired probes, then serve one probe micro-batch and one
-        ingest micro-batch (read lane first: probes don't wait on syncs)."""
-        self._shed_expired()
-        self._step_read()
-        self._step_write()
+        ingest micro-batch (read lane first: probes don't wait on syncs).
+
+        The step's wall and CPU time, context switches and page faults
+        accumulate in the ``step_*_total`` counters and, for a trace, on
+        the ``repro.service.step.usage`` marker."""
+        with obs.span("repro.service.step") as sp:
+            u0 = obs.thread_usage()
+            self._shed_expired()
+            self._step_read()
+            self._step_write()
+            cpu_ns, invol, vol, major, minor = (
+                b - a for a, b in zip(u0, obs.thread_usage()))
+        obs.mark("repro.service.step.usage", cpu_us=cpu_ns / 1e3,
+                 involuntary=invol, voluntary=vol, major_faults=major,
+                 minor_faults=minor)
+        self.metrics.counter("step_cpu_seconds_total").inc(cpu_ns / 1e9)
+        self.metrics.counter("step_wall_seconds_total").inc(sp.seconds)
+        self.metrics.counter("step_involuntary_switches_total").inc(invol)
+        self.metrics.counter("step_major_faults_total").inc(major)
 
     def run(self, max_steps: int = 10_000):
         """Drain both lanes; warn if ``max_steps`` truncates the drain."""
@@ -377,31 +393,38 @@ class DedupeService:
         if not taken:
             return
         rows = sum(r.num_rows for r in taken)
-        keys = np.concatenate([np.asarray(r.keys, np.uint32) for r in taken])
-        valid = np.concatenate([r.valid for r in taken])
         bucket = self.ladder.bucket(rows)
-        pad_k, pad_v = pad_probe_rows(keys, valid, bucket)
-        shape = (bucket, pad_v.shape[1], mode)
-        if shape not in self._seen_shapes:
-            self._seen_shapes.add(shape)
-            self.metrics.counter("bucket_compiles_total").inc()
-        results = t.blocker.query_keys(pad_k, pad_v, include_probe=mode,
-                                       n_real=rows)
-        now = self._clock()
-        self.metrics.counter("probe_batches_total").inc()
-        self.metrics.counter("probe_rows_total").inc(rows)
-        self.metrics.histogram("batch_occupancy", kind="unit").record(
-            rows / bucket)
-        self.metrics.histogram("probe_batch_rows", kind="count").record(rows)
-        off = 0
-        for r in taken:
-            self.metrics.counter("probe_requests_total").inc()
-            self.metrics.histogram("probe_latency_s").record(
-                now - r.submitted_at)
-            self.probe_responses.append(ProbeResponse(
-                r.uid, t.name, STATUS_OK, results[off:off + r.num_rows],
-                now - r.submitted_at))
-            off += r.num_rows
+        start = self._clock()
+        with obs.span("repro.service.read", rows=rows, bucket=bucket,
+                      requests=len(taken),
+                      queued_s_sum=sum(start - r.submitted_at
+                                       for r in taken)):
+            keys = np.concatenate([np.asarray(r.keys, np.uint32)
+                                   for r in taken])
+            valid = np.concatenate([r.valid for r in taken])
+            pad_k, pad_v = pad_probe_rows(keys, valid, bucket)
+            shape = (bucket, pad_v.shape[1], mode)
+            if shape not in self._seen_shapes:
+                self._seen_shapes.add(shape)
+                self.metrics.counter("bucket_compiles_total").inc()
+            results = t.blocker.query_keys(pad_k, pad_v, include_probe=mode,
+                                           n_real=rows)
+            now = self._clock()
+            self.metrics.counter("probe_batches_total").inc()
+            self.metrics.counter("probe_rows_total").inc(rows)
+            self.metrics.histogram("batch_occupancy", kind="unit").record(
+                rows / bucket)
+            self.metrics.histogram("probe_batch_rows",
+                                   kind="count").record(rows)
+            off = 0
+            for r in taken:
+                self.metrics.counter("probe_requests_total").inc()
+                self.metrics.histogram("probe_latency_s").record(
+                    now - r.submitted_at)
+                self.probe_responses.append(ProbeResponse(
+                    r.uid, t.name, STATUS_OK, results[off:off + r.num_rows],
+                    now - r.submitted_at))
+                off += r.num_rows
 
     def _step_write(self) -> None:
         i = self._pick_tenant(self._rr_write, "write_q")
@@ -415,19 +438,22 @@ class DedupeService:
             group_fn=lambda r: r.uid)
         if not taken:
             return
-        keys = np.concatenate([np.asarray(r.keys, np.uint32) for r in taken])
-        valid = np.concatenate([r.valid for r in taken])
-        first_rid = t.store.num_records
-        report = t.blocker.ingest_keys(keys, valid)
-        now = self._clock()
-        self.metrics.counter("ingest_batches_total").inc()
-        self.metrics.counter("ingest_rows_total").inc(int(valid.shape[0]))
-        off = 0
-        for r in taken:
-            self.metrics.counter("ingest_requests_total").inc()
-            self.metrics.histogram("ingest_latency_s").record(
-                now - r.submitted_at)
-            self.ingest_responses.append(IngestResponse(
-                r.uid, t.name, STATUS_OK, report, first_rid + off,
-                r.num_rows, now - r.submitted_at))
-            off += r.num_rows
+        with obs.span("repro.service.write",
+                      rows=sum(r.num_rows for r in taken)):
+            keys = np.concatenate([np.asarray(r.keys, np.uint32)
+                                   for r in taken])
+            valid = np.concatenate([r.valid for r in taken])
+            first_rid = t.store.num_records
+            report = t.blocker.ingest_keys(keys, valid)
+            now = self._clock()
+            self.metrics.counter("ingest_batches_total").inc()
+            self.metrics.counter("ingest_rows_total").inc(int(valid.shape[0]))
+            off = 0
+            for r in taken:
+                self.metrics.counter("ingest_requests_total").inc()
+                self.metrics.histogram("ingest_latency_s").record(
+                    now - r.submitted_at)
+                self.ingest_responses.append(IngestResponse(
+                    r.uid, t.name, STATUS_OK, report, first_rid + off,
+                    r.num_rows, now - r.submitted_at))
+                off += r.num_rows

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core import hashing
 from ..core import hdb as hdb_mod
 from ..core import pairs as pairs_mod
@@ -714,114 +715,126 @@ class DeltaBlocker:
         streaming oracle test pins the non-tipping case exactly).
         """
         cfg = self.cfg
-        keys = np.array(np.asarray(keys_packed), np.uint32, copy=True)
-        valid = np.asarray(valid, bool)
-        q = keys.shape[0]
-        keys[~valid] = _SENT32
-        psize = np.full(valid.shape, INT32_MAX, np.int32)
-        cand_probe: List[np.ndarray] = []
-        cand_rid: List[np.ndarray] = []
-        size_probe: List[np.ndarray] = []
-        size_val: List[np.ndarray] = []
-        hits = np.zeros(q, np.int64)
-        # per-row: a row stops walking when ITS keys die, independent of
-        # batch mates — required for batching invariance of the stat
-        levels_walked = np.zeros(q, np.int64)
-        for lev in range(cfg.max_iterations):
-            state = self.store.levels[lev]
-            if state is None or state.num_rows == 0 or keys.shape[1] == 0:
-                break
-            if not valid.any():
-                break
-            levels_walked += valid.any(axis=1)
-            k64 = pack_key64(keys)
-            idx = sketches.np_cms_indices(cfg.cms, k64)
-            cnts = state.cms_lookup(idx)
-            est = None
-            for j in range(cfg.cms_depth):
-                e = cnts[j].astype(np.int64)
-                if include_probe:
-                    # the probe's own fold-in: +1 per probe entry landing
-                    # in the bucket (exact, incl. self-collisions)
-                    same = ((idx[j][:, :, None] == idx[j][:, None, :])
-                            & valid[:, None, :])
-                    e = e + same.sum(axis=2)
-                est = e if est is None else np.minimum(est, e)
-            est = est.astype(np.int32)
-            p = _pow2(q * keys.shape[1], floor=64)
-            est_p = np.zeros(p, np.int32)
-            val_p = np.zeros(p, bool)
-            psz_p = np.full(p, INT32_MAX, np.int32)
-            m = q * keys.shape[1]
-            est_p[:m] = est.reshape(-1)
-            val_p[:m] = valid.reshape(-1)
-            psz_p[:m] = psize.reshape(-1)
-            right, keepb, _ = _rough_classify(
-                cfg, jnp.asarray(est_p), jnp.asarray(val_p),
-                jnp.asarray(psz_p))
-            right = np.asarray(right)[:m].reshape(valid.shape)
-            keepb = np.asarray(keepb)[:m].reshape(valid.shape)
-            cnt, surv, _ = state.lookup(k64)
-            if include_probe:
-                cnt = cnt + valid.astype(cnt.dtype)
-                surv = self._probe_self_survivors(
-                    k64, valid, cnt, state.lookup_fp(k64),
-                    cfg.max_block_size)
-            accept = right | (keepb & (cnt <= cfg.max_block_size))
-            survive = keepb & (cnt > cfg.max_block_size) & surv
-            size = np.where(keepb, cnt, 0).astype(np.int32)
-            # collect members (and sizes) of matching accepted blocks; the
-            # stat size comes from the accepted-blocks CSR (the key table
-            # never sees CMS-accepted keys), +1 when the probe counts
-            hit_keys = k64[accept]
-            if len(hit_keys):
-                probe_of = np.broadcast_to(
-                    np.arange(q)[:, None], accept.shape)[accept]
-                members = self.store.members_of(hit_keys)
-                for pi, mem in zip(probe_of, members):
-                    if len(mem):
-                        hits[pi] += 1
-                        cand_probe.append(np.full(len(mem), pi))
-                        cand_rid.append(mem)
-                        size_probe.append(np.asarray([pi]))
-                        size_val.append(np.asarray(
-                            [len(mem) + int(include_probe)], np.int64))
-            if not survive.any():
-                break
-            ko = min(cfg.max_oversize_keys, keys.shape[1])
-            if ko < 2:
-                break
-            p = _pow2(q, floor=64)
+        with obs.span("repro.walk"):
+            keys = np.array(np.asarray(keys_packed), np.uint32, copy=True)
+            valid = np.asarray(valid, bool)
+            q = keys.shape[0]
+            keys[~valid] = _SENT32
+            psize = np.full(valid.shape, INT32_MAX, np.int32)
+            cand_probe: List[np.ndarray] = []
+            cand_rid: List[np.ndarray] = []
+            size_probe: List[np.ndarray] = []
+            size_val: List[np.ndarray] = []
+            hits = np.zeros(q, np.int64)
+            # per-row: a row stops walking when ITS keys die, independent
+            # of batch mates — required for batching invariance of the stat
+            levels_walked = np.zeros(q, np.int64)
+            for lev in range(cfg.max_iterations):
+                state = self.store.levels[lev]
+                if state is None or state.num_rows == 0 or keys.shape[1] == 0:
+                    break
+                if not valid.any():
+                    break
+                with obs.span("repro.walk.level", level=lev):
+                    levels_walked += valid.any(axis=1)
+                    with obs.span("repro.walk.cms"):
+                        k64 = pack_key64(keys)
+                        idx = sketches.np_cms_indices(cfg.cms, k64)
+                        cnts = state.cms_lookup(idx)
+                        est = None
+                        for j in range(cfg.cms_depth):
+                            e = cnts[j].astype(np.int64)
+                            if include_probe:
+                                # the probe's own fold-in: +1 per probe
+                                # entry landing in the bucket (exact, incl.
+                                # self-collisions)
+                                same = ((idx[j][:, :, None]
+                                         == idx[j][:, None, :])
+                                        & valid[:, None, :])
+                                e = e + same.sum(axis=2)
+                            est = e if est is None else np.minimum(est, e)
+                        est = est.astype(np.int32)
+                    with obs.span("repro.walk.classify"):
+                        p = _pow2(q * keys.shape[1], floor=64)
+                        est_p = np.zeros(p, np.int32)
+                        val_p = np.zeros(p, bool)
+                        psz_p = np.full(p, INT32_MAX, np.int32)
+                        m = q * keys.shape[1]
+                        est_p[:m] = est.reshape(-1)
+                        val_p[:m] = valid.reshape(-1)
+                        psz_p[:m] = psize.reshape(-1)
+                        right, keepb, _ = _rough_classify(
+                            cfg, jnp.asarray(est_p), jnp.asarray(val_p),
+                            jnp.asarray(psz_p))
+                        right = np.asarray(right)[:m].reshape(valid.shape)
+                        keepb = np.asarray(keepb)[:m].reshape(valid.shape)
+                    with obs.span("repro.walk.lookup"):
+                        cnt, surv, _ = state.lookup(k64)
+                        if include_probe:
+                            cnt = cnt + valid.astype(cnt.dtype)
+                            surv = self._probe_self_survivors(
+                                k64, valid, cnt, state.lookup_fp(k64),
+                                cfg.max_block_size)
+                        accept = right | (keepb & (cnt <= cfg.max_block_size))
+                        survive = keepb & (cnt > cfg.max_block_size) & surv
+                        size = np.where(keepb, cnt, 0).astype(np.int32)
+                    # collect members (and sizes) of matching accepted
+                    # blocks; the stat size comes from the accepted-blocks
+                    # CSR (the key table never sees CMS-accepted keys), +1
+                    # when the probe counts
+                    with obs.span("repro.walk.gather"):
+                        hit_keys = k64[accept]
+                        if len(hit_keys):
+                            probe_of = np.broadcast_to(
+                                np.arange(q)[:, None], accept.shape)[accept]
+                            members = self.store.members_of(hit_keys)
+                            for pi, mem in zip(probe_of, members):
+                                if len(mem):
+                                    hits[pi] += 1
+                                    cand_probe.append(np.full(len(mem), pi))
+                                    cand_rid.append(mem)
+                                    size_probe.append(np.asarray([pi]))
+                                    size_val.append(np.asarray(
+                                        [len(mem) + int(include_probe)],
+                                        np.int64))
+                    if not survive.any():
+                        break
+                    ko = min(cfg.max_oversize_keys, keys.shape[1])
+                    if ko < 2:
+                        break
+                    with obs.span("repro.walk.intersect"):
+                        p = _pow2(q, floor=64)
 
-            def pad_rows(x, fill):
-                out = np.full((p,) + x.shape[1:], fill, x.dtype)
-                out[:q] = x
-                return out
+                        def pad_rows(x, fill):
+                            out = np.full((p,) + x.shape[1:], fill, x.dtype)
+                            out[:q] = x
+                            return out
 
-            (nkhi, nklo), nvalid, npsize, _ = _intersect_keys(
-                cfg, (jnp.asarray(pad_rows(keys[:, :, 0], _SENT32)),
-                      jnp.asarray(pad_rows(keys[:, :, 1], _SENT32))),
-                jnp.asarray(pad_rows(survive, False)),
-                jnp.asarray(pad_rows(size, 0)))
-            keys = np.stack([np.asarray(nkhi)[:q], np.asarray(nklo)[:q]],
-                            axis=-1)
-            valid = np.asarray(nvalid)[:q]
-            psize = np.asarray(npsize)[:q]
-        if cand_probe:
-            cp = np.concatenate(cand_probe)
-            cr = np.concatenate(cand_rid)
-            sp = np.concatenate(size_probe)
-            sv = np.concatenate(size_val)
-        else:
-            cp = np.zeros((0,), np.int64)
-            cr = np.zeros((0,), np.int64)
-            sp = np.zeros((0,), np.int64)
-            sv = np.zeros((0,), np.int64)
-        out = []
-        for pi in range(q if n_real is None else min(n_real, q)):
-            out.append(QueryResult(
-                candidates=np.unique(cr[cp == pi]),
-                n_blocks_hit=int(hits[pi]),
-                levels_walked=int(levels_walked[pi]),
-                block_sizes=np.sort(sv[sp == pi])))
+                        (nkhi, nklo), nvalid, npsize, _ = _intersect_keys(
+                            cfg, (jnp.asarray(pad_rows(keys[:, :, 0], _SENT32)),
+                                  jnp.asarray(pad_rows(keys[:, :, 1], _SENT32))),
+                            jnp.asarray(pad_rows(survive, False)),
+                            jnp.asarray(pad_rows(size, 0)))
+                        keys = np.stack([np.asarray(nkhi)[:q],
+                                         np.asarray(nklo)[:q]], axis=-1)
+                        valid = np.asarray(nvalid)[:q]
+                        psize = np.asarray(npsize)[:q]
+            with obs.span("repro.walk.results"):
+                if cand_probe:
+                    cp = np.concatenate(cand_probe)
+                    cr = np.concatenate(cand_rid)
+                    sp = np.concatenate(size_probe)
+                    sv = np.concatenate(size_val)
+                else:
+                    cp = np.zeros((0,), np.int64)
+                    cr = np.zeros((0,), np.int64)
+                    sp = np.zeros((0,), np.int64)
+                    sv = np.zeros((0,), np.int64)
+                out = []
+                for pi in range(q if n_real is None else min(n_real, q)):
+                    out.append(QueryResult(
+                        candidates=np.unique(cr[cp == pi]),
+                        n_blocks_hit=int(hits[pi]),
+                        levels_walked=int(levels_walked[pi]),
+                        block_sizes=np.sort(sv[sp == pi])))
         return out
