@@ -2,10 +2,13 @@
 
 The iteration state is a dense per-record key matrix (records never move;
 only 64-bit key hashes flow — the paper's data-movement thesis). From the
-second iteration on it is padded to one width, ``intersect_width``, so each
-jitted step compiles for two widths per run. Each host-level iteration runs
-two jit-compiled steps around a host dedupe of the over-sized block
-representatives:
+second iteration on it is padded to one width, ``intersect_width``, so the
+intersection step compiles for two widths per run. The count step runs over
+the live entries only: each iteration gathers every row's leading columns
+that hold its valid entries into a power-of-two bucket of lanes, and counts
+densely where that bucket would reach the whole matrix. Each host-level
+iteration runs two jit-compiled steps around a host dedupe of the over-sized
+block representatives:
 
   1. ROUGH OVER-SIZE DETECTION (Alg. 3): build a Count-Min Sketch over all
      live (record, key) entries, query approximate block sizes. Keys with
@@ -46,6 +49,7 @@ from .u64 import U64
 
 INT32_MAX = np.iinfo(np.int32).max
 _SENT32 = np.uint32(0xFFFFFFFF)
+LANE_FLOOR = 1 << 12  # fewest lanes a compacted count step runs over
 logger = logging.getLogger(__name__)
 
 
@@ -184,24 +188,25 @@ def dedupe_oversized_reps(r_xhi, r_xlo, r_sz, r_khi, r_klo):
     return int(dup.sum()), survivor_in
 
 
-def count_exact(cfg: HDBConfig, key: U64, keep: jnp.ndarray):
+def count_exact(cfg: HDBConfig, key: U64, keep: jnp.ndarray,
+                orig: jnp.ndarray, k: int):
     """Algorithm 4, device half (single-shard fast path — see
     core/distributed.py for the all_to_all + Bloom-broadcast variant).
 
-    Sorts the surviving entries by key; segmented count and XOR of rid
-    fingerprints give every entry its exact block size and membership
-    fingerprint. Returns ``(counted, reps, n_reps, rep_overflow)``:
-    ``counted`` holds the key-sorted entries ``classify_exact`` needs,
-    ``reps`` one (fingerprint, size, key) representative per over-sized
-    block in a ``rep_capacity`` buffer, in key order, padded with
-    sentinels — the input of the host ``dedupe_oversized_reps``.
+    Runs over flat lanes: ``key`` and ``keep`` per lane, and ``orig``,
+    each lane's flat index ``row * k + col`` in the dense ``(n, k)``
+    matrix (``n * k`` for a padding lane). Sorts the surviving entries
+    by key; segmented count and XOR of rid fingerprints give every entry
+    its exact block size and membership fingerprint. Returns
+    ``(counted, reps, n_reps, rep_overflow)``: ``counted`` holds the
+    key-sorted entries ``classify_exact`` needs, ``reps`` one
+    (fingerprint, size, key) representative per over-sized block in a
+    ``rep_capacity`` buffer, in key order, padded with sentinels — the
+    input of the host ``dedupe_oversized_reps``.
     """
-    n, k = keep.shape
-    flat = keep.reshape(-1)
-    nk = n * k
-    khi = jnp.where(flat, key[0].reshape(-1), jnp.uint32(0xFFFFFFFF))
-    klo = jnp.where(flat, key[1].reshape(-1), jnp.uint32(0xFFFFFFFF))
-    orig = jnp.arange(nk, dtype=jnp.int32)
+    lanes = keep.shape[0]
+    khi = jnp.where(keep, key[0], jnp.uint32(0xFFFFFFFF))
+    klo = jnp.where(keep, key[1], jnp.uint32(0xFFFFFFFF))
     (shi, slo), (sorig,) = segments.sort_by_key((khi, klo), [orig])
     srid = sorig // k  # entry (row, col) sits at flat index row * k + col
     skey = (shi, slo)
@@ -218,7 +223,7 @@ def count_exact(cfg: HDBConfig, key: U64, keep: jnp.ndarray):
     # is constant along a block
     rep_ord = jnp.cumsum(reps.astype(jnp.int32)) - 1
     n_reps = rep_ord[-1] + 1
-    rep_idx = jnp.nonzero(reps, size=cfg.rep_capacity, fill_value=nk - 1)[0]
+    rep_idx = jnp.nonzero(reps, size=cfg.rep_capacity, fill_value=lanes - 1)[0]
     rep_valid = jnp.arange(cfg.rep_capacity, dtype=jnp.int32) < n_reps
     rep_overflow = jnp.maximum(n_reps - cfg.rep_capacity, 0)
     rep_lanes = (
@@ -235,7 +240,8 @@ def count_exact(cfg: HDBConfig, key: U64, keep: jnp.ndarray):
 def classify_exact(keep: jnp.ndarray, counted, survivor: jnp.ndarray):
     """Algorithm 4, second device half: classify the key-sorted entries
     given ``survivor``, the host dedupe's flag for each representative
-    lane of ``count_exact`` (``rep_capacity`` long).
+    lane of ``count_exact`` (``rep_capacity`` long). A padding lane's
+    ``sorig`` is ``n * k``, out of range: its write is dropped.
 
     Returns dense masks shaped like ``keep``:
       right_exact: entries whose block the CMS over-counted
@@ -254,11 +260,12 @@ def classify_exact(keep: jnp.ndarray, counted, survivor: jnp.ndarray):
                       & survivor[jnp.clip(rep_ord, 0, cap - 1)])
 
     # one scatter back to the dense layout: the size in the low 30 bits,
-    # the two masks in the top two
+    # the two masks in the top two; slots no lane holds stay 0
     code = (jnp.where(live, counted["sizes"], 0).astype(jnp.uint32)
             | (survive_sorted.astype(jnp.uint32) << 30)
             | ((live & ~over).astype(jnp.uint32) << 31))
-    dense = jnp.zeros((nk,), jnp.uint32).at[sorig].set(code).reshape(n, k)
+    dense = (jnp.zeros((nk,), jnp.uint32).at[sorig].set(code, mode="drop")
+             .reshape(n, k))
     right_exact = (dense >> 31).astype(bool) & keep
     survive = ((dense >> 30) & 1).astype(bool) & keep
     size = (dense & jnp.uint32((1 << 30) - 1)).astype(jnp.int32)
@@ -324,13 +331,86 @@ def intersect_keys(cfg: HDBConfig, key: U64, survive: jnp.ndarray,
     return (s_khi, s_klo), out_valid, s_psize, n_dropped_max_keys
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _count_step(cfg: HDBConfig, keys_packed: jnp.ndarray, valid: jnp.ndarray,
-                psize: jnp.ndarray):
-    key = (keys_packed[..., 0], keys_packed[..., 1])
-    right_cms, keep, dropped_sim, _ = rough_oversize_detection(cfg, key, valid, psize)
-    counted, reps, n_reps, rep_overflow = count_exact(cfg, key, keep)
-    return (right_cms, keep, dropped_sim), counted, reps, n_reps, rep_overflow
+def row_extents(valid: jnp.ndarray) -> jnp.ndarray:
+    """Per row, one past its last valid column (0 for a row with none):
+    every valid entry of row ``r`` lies in its first ``extent[r]`` columns.
+
+    ``intersect_keys`` row-sorts its output with the sentinel keys last
+    and ``pad_to_intersect_width`` appends sentinel columns, so from the
+    second iteration on a row's extent is about its count of valid
+    entries, whatever the padded width.
+    """
+    cols = jnp.arange(1, valid.shape[1] + 1, dtype=jnp.int32)
+    return jnp.max(jnp.where(valid, cols, 0), axis=1)
+
+
+@jax.jit
+def lanes_needed(valid: jnp.ndarray) -> jnp.ndarray:
+    """Lanes the count step's compaction gathers: the sum of the rows'
+    extents, at least the number of valid entries."""
+    return jnp.sum(row_extents(valid))
+
+
+def lane_bucket(needed: int, slots: int) -> int:
+    """Lanes the count step runs over: the next power of two at or above
+    ``needed``, at least ``LANE_FLOOR``, or all ``slots`` (dense) where
+    that bucket would reach them."""
+    bucket = max(LANE_FLOOR, 1 << max(needed - 1, 0).bit_length())
+    return slots if bucket >= slots else bucket
+
+
+def compact_lanes(valid: jnp.ndarray, lanes: int) -> jnp.ndarray:
+    """The dense flat index ``row * k + col`` of each of ``lanes`` lanes:
+    the first ``extent`` columns of each row (``row_extents``), rows in
+    order, then padding lanes at ``n * k``. ``lanes`` must hold
+    ``lanes_needed(valid)``. Indices ascend along the lanes, as in the
+    dense matrix."""
+    n, k = valid.shape
+    extent = row_extents(valid)
+    end = jnp.cumsum(extent)
+    start = end - extent
+    # each lane's row: every row marks its first lane, a running max
+    # fills the rest (a row with no extent shares its start with the next
+    # row that has one, and the larger row id wins)
+    row = jax.lax.cummax(
+        jnp.zeros((lanes,), jnp.int32).at[start].max(
+            jnp.arange(n, dtype=jnp.int32), mode="drop"))
+    lane = jnp.arange(lanes, dtype=jnp.int32)
+    return jnp.where(lane < end[-1], row * k + lane - start[row], n * k)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _count_step(cfg: HDBConfig, lanes: int, keys_packed: jnp.ndarray,
+                valid: jnp.ndarray, psize: jnp.ndarray):
+    """Algorithms 3 and 4 over ``lanes`` lanes: the whole ``(n, k)``
+    matrix when ``lanes >= n * k``, else its live part gathered by
+    ``compact_lanes``. The rough masks come back in the dense layout."""
+    n, k = valid.shape
+    dense = lanes >= n * k
+    flat = (keys_packed[..., 0].reshape(-1), keys_packed[..., 1].reshape(-1),
+            valid.reshape(-1), psize.reshape(-1))
+    if dense:
+        orig = jnp.arange(n * k, dtype=jnp.int32)
+        khi, klo, live, ps = flat
+    else:
+        orig = compact_lanes(valid, lanes)
+        at = jnp.minimum(orig, n * k - 1)
+        khi, klo, live, ps = (x[at] for x in flat)
+        live = live & (orig < n * k)
+    right_cms, keep, dropped_sim, _ = rough_oversize_detection(
+        cfg, (khi, klo), live, ps)
+    counted, reps, n_reps, rep_overflow = count_exact(
+        cfg, (khi, klo), keep, orig, k)
+    if dense:
+        rough = tuple(m.reshape(n, k) for m in (right_cms, keep, dropped_sim))
+    else:
+        # one scatter back to the dense layout, padding lanes dropped
+        code = (right_cms.astype(jnp.uint8) | (keep.astype(jnp.uint8) << 1)
+                | (dropped_sim.astype(jnp.uint8) << 2))
+        packed = (jnp.zeros((n * k,), jnp.uint8).at[orig].set(code, mode="drop")
+                  .reshape(n, k))
+        rough = tuple(((packed >> b) & 1).astype(bool) for b in range(3))
+    return rough, counted, reps, n_reps, rep_overflow
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -350,23 +430,29 @@ def _intersect_step(cfg: HDBConfig, keys_packed: jnp.ndarray,
         "n_dropped_similarity": jnp.sum(dropped_sim.astype(jnp.int32)),
         "n_dropped_max_keys": n_dropped_mk,
         "n_surviving_entries": jnp.sum(survive.astype(jnp.int32)),
+        "next_lanes_needed": lanes_needed(new_valid),
     }
     new_state = (jnp.stack([new_key[0], new_key[1]], axis=-1), new_valid, new_psize)
     return accepted, new_state, stats
 
 
 def hdb_iteration(cfg: HDBConfig, keys_packed: jnp.ndarray, valid: jnp.ndarray,
-                  psize: jnp.ndarray):
+                  psize: jnp.ndarray, lanes: int | None = None):
     """One full HDB iteration. Returns (accepted_mask, new_state, stats).
 
     Two jitted device steps (CMS + exact counts; classification +
     intersection) around the host dedupe of the over-sized
     representatives, whose survivor flags go back as one fixed
-    ``rep_capacity`` mask so the second step compiles once per key width.
+    ``rep_capacity`` mask so the second step compiles once per key width
+    and lane count. The count step runs over ``lanes`` lanes
+    (``lane_bucket``; read from ``valid`` when not given); ``stats``
+    carries ``next_lanes_needed``, the ``lanes_needed`` of the new state.
     """
+    if lanes is None:
+        lanes = lane_bucket(int(lanes_needed(valid)), valid.size)
     with obs.span("repro.hdb.count"):
         rough, counted, reps, n_reps, rep_overflow = _count_step(
-            cfg, keys_packed, valid, psize)
+            cfg, lanes, keys_packed, valid, psize)
         m = min(int(n_reps), cfg.rep_capacity)
         reps = [np.asarray(r)[:m] for r in reps]
     with obs.span("repro.hdb.reps"):
@@ -407,10 +493,12 @@ def hashed_dynamic_blocking(
     acc_hi: List[np.ndarray] = []
     acc_lo: List[np.ndarray] = []
     all_stats: List[IterationStats] = []
+    needed = int(lanes_needed(valid))
     for it in range(cfg.max_iterations):
         with obs.span("repro.hdb.iteration", iteration=it):
+            lanes = lane_bucket(needed, valid.size)
             accepted, (new_keys, new_valid, new_psize), stats = hdb_iteration(
-                cfg, keys_packed, valid, psize)
+                cfg, keys_packed, valid, psize, lanes)
             with obs.span("repro.hdb.accept"):
                 acc_np = np.asarray(accepted)
                 ridx, kidx = np.nonzero(acc_np)
@@ -418,11 +506,11 @@ def hashed_dynamic_blocking(
                 acc_rid.append(ridx.astype(np.int64))
                 acc_hi.append(keys_np[ridx, kidx, 0])
                 acc_lo.append(keys_np[ridx, kidx, 1])
+                needed = int(stats.pop("next_lanes_needed"))
                 st = IterationStats(iteration=it, **{k: int(v) for k, v in
                                                      stats.items()})
             obs.mark("repro.hdb.iteration.counts",
-                     slots=valid.shape[0] * valid.shape[1],
-                     live=st.n_live_keys,
+                     slots=valid.size, live=st.n_live_keys, lanes=lanes,
                      reps=st.n_duplicate_blocks + st.n_surviving_oversized,
                      surviving=st.n_surviving_oversized)
         all_stats.append(st)

@@ -190,3 +190,139 @@ def test_cms_overcount_recovery(built):
         return set(zip(r.rids.tolist(), r.key_hi.tolist(), r.key_lo.tolist()))
     assert key_set(big) == key_set(small)
     assert sum(s.n_right_exact for s in small.stats) > 0
+
+
+def _padded_state(rng, n, k, needed, card, cfg):
+    """A state in the layout ``intersect_keys`` + ``pad_to_intersect_width``
+    leave: each row's keys sorted and front-packed, sentinel columns after,
+    a few interior entries invalid (as a repeated key is), and
+    ``lanes_needed`` exactly ``needed``."""
+    cap = min(k, 2 * needed // n + 1)
+    extent = np.minimum(rng.integers(0, cap + 1, n), k)
+    while extent.sum() != needed:  # move the sum onto ``needed``
+        d = np.sign(needed - extent.sum())
+        room = np.flatnonzero((extent < k) if d > 0 else (extent > 0))
+        extent[rng.choice(room)] += d
+    khi = np.full((n, k), 0xFFFFFFFF, np.uint32)
+    klo = khi.copy()
+    valid = np.zeros((n, k), bool)
+    for r, e in enumerate(extent):
+        ids = np.sort(rng.choice(card, e, replace=False)).astype(np.uint32)
+        khi[r, :e], klo[r, :e] = ids, ids * np.uint32(2654435761)
+        valid[r, :e] = True
+        valid[r, :max(e - 1, 0)] &= rng.random(max(e - 1, 0)) > 0.05
+    psize = np.where(valid, rng.integers(cfg.max_block_size + 1,
+                                         10 * cfg.max_block_size, (n, k)), 0)
+    return (jnp.asarray(np.stack([khi, klo], -1)), jnp.asarray(valid),
+            jnp.asarray(psize.astype(np.int32)))
+
+
+def _iteration(cfg, state, lanes):
+    acc, new_state, stats = hdb.hdb_iteration(cfg, *state, lanes)
+    return ([np.asarray(acc)] + [np.asarray(x) for x in new_state],
+            {k: int(v) for k, v in stats.items()})
+
+
+@pytest.mark.parametrize("case", ["deep_run", "pow2", "floor", "dense"])
+def test_compacted_count_matches_dense(case, request, monkeypatch):
+    """The count step over the compacted live lanes gives what the dense
+    count over every slot gives: accepted entries, the next state and
+    every statistic, bit for bit."""
+    if case == "deep_run":
+        # >= 3 iterations, the first dense, the later ones sparse
+        keys, valid = request.getfixturevalue("built")
+        cfg = hdb.HDBConfig(max_block_size=20, cms_width=1 << 12)
+        chosen = []
+        bucket = hdb.lane_bucket
+        monkeypatch.setattr(hdb, "lane_bucket", lambda needed, slots: chosen.append(
+            (bucket(needed, slots), slots)) or chosen[-1][0])
+        got = hdb.hashed_dynamic_blocking(keys, valid, cfg)
+        monkeypatch.setattr(hdb, "lane_bucket", lambda needed, slots: slots)
+        want = hdb.hashed_dynamic_blocking(keys, valid, cfg)
+        assert len(got.stats) >= 3
+        assert chosen[0][0] == chosen[0][1]          # iteration 0 dense
+        assert all(lanes < slots for lanes, slots in chosen[1:])
+        assert chosen[-1][0] == hdb.LANE_FLOOR
+        assert got.stats == want.stats
+        for a, b in [(got.rids, want.rids), (got.key_hi, want.key_hi),
+                     (got.key_lo, want.key_lo)]:
+            np.testing.assert_array_equal(a, b)
+        return
+    n, k = 1024, 120
+    needed, expect = {"pow2": (8192, 8192), "floor": (300, hdb.LANE_FLOOR),
+                      "dense": (70000, n * k)}[case]
+    # ~40 entries a key and about as many CMS buckets as keys: blocks on
+    # both sides of max_block_size, and CMS over-counts to recover
+    card = needed // 40 + 2
+    cfg = hdb.HDBConfig(max_block_size=30, rep_capacity=1 << 12,
+                        cms_width=1 << (card - 1).bit_length())
+    state = _padded_state(np.random.default_rng(11), n, k, needed, card, cfg)
+    assert int(hdb.lanes_needed(state[1])) == needed
+    assert hdb.lane_bucket(needed, n * k) == expect
+    chosen_arrays, chosen_stats = _iteration(cfg, state, None)
+    # the chosen layout against the other one: the dense count, or the
+    # tightest compaction where the dense count was chosen
+    other = needed if expect == n * k else n * k
+    other_arrays, other_stats = _iteration(cfg, state, other)
+    assert chosen_stats == other_stats
+    # both outcomes occur: accepted blocks and over-sized survivors
+    assert chosen_stats["n_right_cms"] + chosen_stats["n_right_exact"] > 0
+    assert chosen_stats["n_surviving_oversized"] > 0
+    for a, b in zip(chosen_arrays, other_arrays):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_intersection_output_is_front_packed(built):
+    """What the gathered compaction relies on: after ``intersect_keys``
+    and ``pad_to_intersect_width`` each row's valid entries lie within its
+    first ``c_r`` columns (``c_r`` its non-sentinel keys), and
+    ``compact_lanes`` hands every valid slot to exactly one lane, in
+    dense order."""
+    keys, valid = built
+    cfg = hdb.HDBConfig(max_block_size=20, cms_width=1 << 12)
+    psize = jnp.asarray(np.full(valid.shape, hdb.INT32_MAX, np.int32))
+    _, (keys1, valid1, _), _ = hdb.hdb_iteration(cfg, keys, valid, psize)
+    keys1, valid1 = np.asarray(keys1), np.asarray(valid1)
+    n, k = valid1.shape
+    assert k == cfg.intersect_width and valid1.any()
+    c = (~((keys1[..., 0] == 0xFFFFFFFF) & (keys1[..., 1] == 0xFFFFFFFF))
+         ).sum(axis=1)
+    assert not (valid1 & (np.arange(k)[None, :] >= c[:, None])).any()
+    extent = np.asarray(hdb.row_extents(jnp.asarray(valid1)))
+    assert (extent <= c).all()
+    needed = int(hdb.lanes_needed(jnp.asarray(valid1)))
+    assert valid1.sum() <= needed == extent.sum()
+    lanes = hdb.lane_bucket(needed, n * k)
+    assert needed <= lanes < n * k
+    orig = np.asarray(hdb.compact_lanes(jnp.asarray(valid1), lanes))
+    real = orig[orig < n * k]
+    assert len(real) == needed and (orig[needed:] == n * k).all()
+    assert (np.diff(real) > 0).all()
+    assert set(np.flatnonzero(valid1.reshape(-1))) <= set(real.tolist())
+
+
+def test_padding_lanes_never_overwrite_a_real_slot():
+    """The compacted count has padding lanes past the live ones; their
+    writes into the dense layout are dropped, so the slot-0 entry of an
+    over-sized block keeps its exact size and survival."""
+    n, k, m = 64, 8, 40
+    khi = np.full((n, k), 0xFFFFFFFF, np.uint32)
+    klo = khi.copy()
+    khi[:m, 0], klo[:m, 0] = 5, 6       # one 40-record block in column 0
+    valid = khi != 0xFFFFFFFF
+    state = (jnp.asarray(np.stack([khi, klo], -1)), jnp.asarray(valid),
+             jnp.asarray(np.where(valid, 1000, 0).astype(np.int32)))
+    cfg = hdb.HDBConfig(max_block_size=10, cms_width=1 << 8,
+                        rep_capacity=1 << 4)
+    lanes = 128                          # 40 live lanes, 88 padding lanes
+    rough, counted, _, n_reps, _ = hdb._count_step(cfg, lanes, *state)
+    assert int(n_reps) == 1
+    survivor = jnp.ones((cfg.rep_capacity,), bool)
+    _, survive, size = hdb.classify_exact(rough[1], counted, survivor)
+    np.testing.assert_array_equal(np.asarray(survive), valid)
+    np.testing.assert_array_equal(np.asarray(size)[:, 0],
+                                  np.where(valid[:, 0], m, 0))
+    assert int((np.asarray(counted["sorig"]) == n * k).sum()) == lanes - m
+    dense, _, _, _, _ = hdb._count_step(cfg, n * k, *state)
+    for a, b in zip(rough, dense):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -136,12 +136,14 @@ def test_batch_counts_ride_on_spans(batch_trace):
     assert len(iters) >= 2
     counts = _named(spans, "repro.hdb.iteration.counts")
     assert len(counts) == len(iters)
-    # the first iteration counts over every top-level key of every record
+    # the first iteration counts densely over every top-level key of
+    # every record; later ones over a compaction of their live entries
     assert counts[0][3]["slots"] == rep.num_records * width
+    assert counts[0][3]["lanes"] == counts[0][3]["slots"]
     for _, _, _, args in counts:
-        assert set(args) == {"slots", "live", "reps", "surviving"}
+        assert set(args) == {"slots", "live", "lanes", "reps", "surviving"}
         assert 0 <= args["surviving"] <= args["reps"] <= args["live"] \
-            <= args["slots"]
+            <= args["lanes"] <= args["slots"]
     (sort,) = _named(spans, "repro.pairs.sort")
     assert sort[3] == {"slots": rep.pairs.total_slots}
     (pairs,) = _named(spans, "repro.pairs.sort.counts")
