@@ -10,12 +10,15 @@ record sharding (blocking has no "model" dimension). Per iteration:
              fingerprints with a local sort (keys are fully local after
              routing).
   - Dedupe:  block representatives hash-route BY FINGERPRINT with a second
-             (much smaller) all_to_all; survivors are all-gathered as the
-             paper's "broadcasted counts map"; a Bloom filter over ALL
-             over-sized keys is OR-merged so shards can recover
-             CMS-over-counted right-sized blocks exactly as in Algorithm 4
-             (key not in Bloom => right-sized; in counts map => over-sized;
-             otherwise duplicate, dropped).
+             (much smaller) all_to_all; every over-sized key, with its
+             size where it survives the dedupe, is all-gathered as the
+             paper's "broadcasted counts map", so shards recover
+             CMS-over-counted right-sized blocks as in Algorithm 4 (key
+             not in the map => right-sized; in it with a size =>
+             over-sized; in it without => duplicate, dropped). The paper
+             keeps the over-sized keys in a Bloom filter; the map holds
+             at most n_shards * rep_capacity_per_shard keys, so it is
+             exact here at no cost beyond the survivor map.
   - Intersect: purely record-local (Alg. 2), no communication.
 
 Record payloads never move; the only shuffled bytes are 8-byte key hashes
@@ -63,6 +66,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import hashing, routing, segments, sketches, u64
+from .. import obs
 from ..distributed import sharding
 from .hdb import (BlockingResult, HDBConfig, INT32_MAX, IterationStats,
                   RepCapacityWarning, intersect_keys,
@@ -78,8 +82,68 @@ class DistConfig:
 
     route_slack: float = 2.0       # all_to_all bucket slack over the mean
     rep_capacity_per_shard: int = 1 << 14
-    bloom_slots: int = 1 << 22
-    bloom_hashes: int = 20
+
+
+@dataclasses.dataclass(frozen=True)
+class Exchange:
+    """What one ``all_to_all`` of a sharded run carried, as counted on the
+    device: ``sent[s, d]`` live entries that shard ``s`` routed to shard
+    ``d``, before each destination bucket's ``cap`` (a count over ``cap``
+    overflowed). ``stage`` is ``"hdb"`` for the exact-count exchange of
+    block-key entries (u64 key + int32 record id), ``index`` its
+    iteration; ``"pairs"`` for a round of 62-bit pair sort words."""
+
+    stage: str
+    index: int
+    sent: np.ndarray    # (n_shards, n_shards) int
+    cap: int
+
+    @property
+    def fill(self) -> int:
+        """The fullest destination bucket."""
+        return int(self.sent.max())
+
+    @property
+    def off_chip(self) -> np.ndarray:
+        """Live entries each shard sent to the others."""
+        return self.sent.sum(axis=1) - np.diag(self.sent)
+
+    def span_args(self) -> dict:
+        """``fill``, ``cap`` and one ``off_chip_<shard>`` count per shard,
+        as program-span arguments."""
+        return dict(fill=self.fill, cap=self.cap,
+                    **{f"off_chip_{i}": int(v)
+                       for i, v in enumerate(self.off_chip)})
+
+
+def _route_cap(n_entries: int, n_shards: int, slack: float) -> int:
+    """Per-destination bucket capacity for ``n_entries`` routed lanes."""
+    return int(np.ceil(n_entries / n_shards * slack))
+
+
+def _sent_matrix(owner, n_shards: int, axes):
+    """Inside a shard_mapped body: every shard's live entries per
+    destination (``owner == n_shards`` is dropped), all-gathered into the
+    ``(n_shards, n_shards)`` ``Exchange.sent`` matrix."""
+    sent = jnp.stack([jnp.sum((owner == d).astype(jnp.int32))
+                      for d in range(n_shards)])
+    return jax.lax.all_gather(sent, axes)
+
+
+def _sort_reps(xhi, xlo, sz, khi, klo, live):
+    """Representative lanes in (fingerprint, size, key) order, padding
+    last: three stable sorts of a lane index, least significant key
+    first. No two lanes share a key (each block has one owner and one
+    representative; padding lanes are all alike), so this is the order
+    of one 5-key ``lax.sort`` of the six arrays. Compiled for a described
+    v5e 2x2 at 131,200 lanes on an 8-core CPU host, beside other
+    compiles, that sort took 211 s and these three 51 s together."""
+    order = jnp.arange(xhi.shape[0], dtype=jnp.int32)
+    _, _, order = jax.lax.sort((khi, klo, order), num_keys=2, is_stable=True)
+    _, order = jax.lax.sort((sz[order], order), num_keys=1, is_stable=True)
+    _, _, order = jax.lax.sort((xhi[order], xlo[order], order), num_keys=2,
+                               is_stable=True)
+    return tuple(x[order] for x in (xhi, xlo, sz, khi, klo, live))
 
 
 def make_hdb_step(cfg: HDBConfig, mesh: Mesh,
@@ -102,7 +166,6 @@ def _make_hdb_step_cached(cfg: HDBConfig, mesh: Mesh,
                           dist: DistConfig):
     n_shards = sharding.axis_size(mesh, tuple(axis_names))
     axes = tuple(axis_names)
-    bloom_cfg = sketches.BloomConfig(dist.bloom_slots, dist.bloom_hashes)
 
     def local_step(keys_packed, valid, psize):
         n_loc, k = valid.shape
@@ -132,8 +195,9 @@ def _make_hdb_step_cached(cfg: HDBConfig, mesh: Mesh,
         owner = jnp.where(flat_keep,
                           (owner_h % jnp.uint32(n_shards)).astype(jnp.int32),
                           jnp.int32(n_shards))
-        cap = int(np.ceil(L / n_shards * dist.route_slack))
+        cap = _route_cap(L, n_shards, dist.route_slack)
         bhi, blo, (brid,), route_overflow = _route(khi, klo, [rid], owner, n_shards, cap)
+        route_sent = _sent_matrix(owner, n_shards, axes)
         bhi, blo, brid = routing.exchange(axes, bhi, blo, brid)
 
         # ---- owner-side exact counts + fingerprints (local sort) ----
@@ -147,10 +211,6 @@ def _make_hdb_step_cached(cfg: HDBConfig, mesh: Mesh,
         xors = segments.segment_xor(skey, fp)
         over = live & (sizes > cfg.max_block_size)
         reps = segments.segment_starts(skey) & over
-
-        # Bloom over ALL over-sized keys (H_O), OR-merged across shards
-        bloom = sketches.bloom_build(bloom_cfg, skey, reps)
-        bloom = jax.lax.pmax(bloom, axes)
 
         # ---- dedupe: route representatives by membership fingerprint ----
         rcap = dist.rep_capacity_per_shard
@@ -172,10 +232,9 @@ def _make_hdb_step_cached(cfg: HDBConfig, mesh: Mesh,
             r_xhi, r_xlo, [r_sz, r_khi, r_klo, r_live], xowner, n_shards, xcap)
         xhi_b, xlo_b, xsz_b, xkhi_b, xklo_b, xlive_b = routing.exchange(
             axes, xhi_b, xlo_b, xsz_b, xkhi_b, xklo_b, xlive_b)
-        g_xhi, g_xlo, g_sz, g_khi, g_klo, g_live = jax.lax.sort(
-            (xhi_b.reshape(-1), xlo_b.reshape(-1), xsz_b.reshape(-1),
-             xkhi_b.reshape(-1), xklo_b.reshape(-1), xlive_b.reshape(-1)),
-            num_keys=5)
+        g_xhi, g_xlo, g_sz, g_khi, g_klo, g_live = _sort_reps(
+            xhi_b.reshape(-1), xlo_b.reshape(-1), xsz_b.reshape(-1),
+            xkhi_b.reshape(-1), xklo_b.reshape(-1), xlive_b.reshape(-1))
         dup = ((g_xhi == jnp.roll(g_xhi, 1)) & (g_xlo == jnp.roll(g_xlo, 1))
                & (g_sz == jnp.roll(g_sz, 1)))
         dup = dup.at[0].set(False)
@@ -184,9 +243,11 @@ def _make_hdb_step_cached(cfg: HDBConfig, mesh: Mesh,
         n_dup = jnp.sum((is_real & dup).astype(jnp.int32))
         n_dup = jax.lax.psum(n_dup, axes)
 
-        # ---- broadcast the survivor counts map (all_gather + sort) ----
-        t_khi = jnp.where(survivor, g_khi, jnp.uint32(0xFFFFFFFF))
-        t_klo = jnp.where(survivor, g_klo, jnp.uint32(0xFFFFFFFF))
+        # ---- broadcast the counts map (all_gather + sort): every
+        # over-sized key once (one owner, one representative), sized
+        # where it survived the dedupe and 0 where it is a duplicate ----
+        t_khi = jnp.where(is_real, g_khi, jnp.uint32(0xFFFFFFFF))
+        t_klo = jnp.where(is_real, g_klo, jnp.uint32(0xFFFFFFFF))
         t_sz = jnp.where(survivor, g_sz, 0)
         t_khi = jax.lax.all_gather(t_khi, axes, tiled=True)
         t_klo = jax.lax.all_gather(t_klo, axes, tiled=True)
@@ -194,12 +255,16 @@ def _make_hdb_step_cached(cfg: HDBConfig, mesh: Mesh,
         t_khi, t_klo, t_sz = jax.lax.sort((t_khi, t_klo, t_sz), num_keys=2)
 
         # ---- classify original local entries (paper Alg. 4 lines 9-19) ----
-        in_bloom = sketches.bloom_query(bloom_cfg, bloom, (khi, klo)).reshape(valid.shape)
-        hit, ex_size = segments.lookup_u64((t_khi, t_klo), t_sz, (khi, klo), 0)
-        hit = hit.reshape(valid.shape)
+        over_sized, ex_size = segments.lookup_u64((t_khi, t_klo), t_sz,
+                                                  (khi, klo), 0)
+        over_sized = over_sized.reshape(valid.shape)
         ex_size = ex_size.reshape(valid.shape)
-        right_exact = keep & ~in_bloom
-        survive = keep & hit
+        # an entry lost to a full buffer can hide an over-sized key from
+        # the map: then no key is accepted on the exact count, so no
+        # accepted block ever passes max_block_size (the loss is counted)
+        lost = jax.lax.psum(rep_overflow + route_overflow + x_overflow, axes)
+        right_exact = keep & ~over_sized & (lost == 0)
+        survive = keep & (ex_size > 0)
         accepted = right_cms | right_exact
 
         # ---- intersect locally (Alg. 2) ----
@@ -221,8 +286,8 @@ def _make_hdb_step_cached(cfg: HDBConfig, mesh: Mesh,
             "n_surviving_oversized": jax.lax.psum(
                 jnp.sum(survivor.astype(jnp.int32)), axes),
             "n_surviving_entries": tot(survive),
-            "rep_overflow": jax.lax.psum(rep_overflow + route_overflow
-                                         + x_overflow, axes),
+            "rep_overflow": lost,
+            "route_sent": route_sent,
         }
         new_packed = jnp.stack([new_key[0], new_key[1]], axis=-1)
         return accepted, new_packed, new_valid, new_psize, stats
@@ -232,7 +297,7 @@ def _make_hdb_step_cached(cfg: HDBConfig, mesh: Mesh,
     stats_spec = {k: P() for k in [
         "n_live_keys", "n_right_cms", "n_right_exact", "n_dropped_similarity",
         "n_dropped_max_keys", "n_duplicate_blocks", "n_surviving_oversized",
-        "n_surviving_entries", "rep_overflow"]}
+        "n_surviving_entries", "rep_overflow", "route_sent"]}
     mapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(spec3, spec2, spec2),
@@ -269,15 +334,28 @@ def distributed_hashed_dynamic_blocking(
     acc_hi: List[np.ndarray] = []
     acc_lo: List[np.ndarray] = []
     all_stats: List[IterationStats] = []
+    exchanges: List[Exchange] = []
     for it in range(start_iteration, cfg.max_iterations):
-        accepted, new_keys, new_valid, new_psize, stats = step(keys_packed, valid, psize)
-        acc = np.asarray(accepted)
-        ridx, kidx = np.nonzero(acc)
-        keys_np = np.asarray(keys_packed)
-        acc_rid.append(ridx.astype(np.int64))
-        acc_hi.append(keys_np[ridx, kidx, 0])
-        acc_lo.append(keys_np[ridx, kidx, 1])
-        st = IterationStats(iteration=it, **{k: int(v) for k, v in stats.items()})
+        with obs.span("repro.dist.hdb.iteration", iteration=it):
+            with obs.span("repro.dist.hdb.step"):
+                accepted, new_keys, new_valid, new_psize, stats = step(
+                    keys_packed, valid, psize)
+            with obs.span("repro.dist.hdb.accept"):
+                acc = np.asarray(accepted)
+                ridx, kidx = np.nonzero(acc)
+                keys_np = np.asarray(keys_packed)
+                acc_rid.append(ridx.astype(np.int64))
+                acc_hi.append(keys_np[ridx, kidx, 0])
+                acc_lo.append(keys_np[ridx, kidx, 1])
+                ex = Exchange("hdb", it, np.asarray(stats.pop("route_sent")),
+                              _route_cap(valid.size // n_shards, n_shards,
+                                         dist.route_slack))
+                st = IterationStats(iteration=it, **{k: int(v) for k, v in
+                                                     stats.items()})
+            obs.mark("repro.dist.hdb.iteration.counts", slots=valid.size,
+                     live=st.n_live_keys, rep_overflow=st.rep_overflow,
+                     **ex.span_args())
+        exchanges.append(ex)
         all_stats.append(st)
         logger.log(logging.INFO if verbose else logging.DEBUG,
                    "[hdb-dist] iter=%d %s", it, st)
@@ -297,6 +375,7 @@ def distributed_hashed_dynamic_blocking(
         key_lo=np.concatenate(acc_lo) if acc_lo else np.zeros((0,), np.uint32),
         stats=all_stats,
         num_records=n,
+        exchanges=exchanges,
     )
 
 
@@ -336,9 +415,10 @@ def _make_routed_round_step(mesh, axes, n_shards: int, chunk: int, cap: int,
     Exact mode decodes slots [base, base+chunk) per shard (``total`` is a
     traced scalar operand so different datasets share one executable);
     sampled mode decodes pre-split (block, local) slot chunks. Both
-    return this shard's routed sort-word buckets plus the psum'd route
-    overflow. Cached: repeated drivers over the same mesh geometry reuse
-    the compiled step instead of re-jitting per call.
+    return this shard's routed sort-word buckets, the psum'd route
+    overflow and the round's ``Exchange.sent`` matrix. Cached: repeated
+    drivers over the same mesh geometry reuse the compiled step instead
+    of re-jitting per call.
     """
     from ..kernels import pairs as pairs_kernels
 
@@ -349,7 +429,8 @@ def _make_routed_round_step(mesh, axes, n_shards: int, chunk: int, cap: int,
             hi, lo, [], owner, n_shards, cap)
         bhi, blo = routing.exchange(axes, bhi, blo)
         return (bhi.reshape(-1), blo.reshape(-1),
-                jax.lax.psum(overflow, axes))
+                jax.lax.psum(overflow, axes),
+                _sent_matrix(owner, n_shards, axes))
 
     if sampled:
         def local_round(start, size, members, block, local, valid):
@@ -371,7 +452,7 @@ def _make_routed_round_step(mesh, axes, n_shards: int, chunk: int, cap: int,
 
     return jax.jit(jax.shard_map(
         local_round, mesh=mesh, in_specs=in_specs,
-        out_specs=(P(axes), P(axes), P()), check_vma=False))
+        out_specs=(P(axes), P(axes), P(), P()), check_vma=False))
 
 
 @functools.lru_cache(maxsize=64)
@@ -416,6 +497,23 @@ def _make_decode_round_step(mesh, axes, chunk: int):
         check_vma=False))
 
 
+def _single_device_pairs(blocks, budget: int, sample_seed: int,
+                         sort_backend: str, reason: str, mark: str,
+                         exchanges=()):
+    """The single-device engine's PairSet in place of the routed one,
+    marked in the trace (``repro.dist.pairs.fallback``) and on the set
+    (``PairSet.fallback``), so that no run takes it unawares."""
+    from . import pairs as pairs_lib
+
+    obs.mark("repro.dist.pairs.fallback", reason=mark)
+    pset = pairs_lib.dedupe_pairs(blocks, budget=budget,
+                                  sample_seed=sample_seed,
+                                  sort_backend=sort_backend)
+    pset.fallback = reason
+    pset.exchanges = list(exchanges)
+    return pset
+
+
 def dedupe_pairs_distributed(
     blocks, mesh: Mesh, axis_names: Sequence[str] = ("data",),
     budget: int = 50_000_000, chunk_per_shard: int = 1 << 18,
@@ -443,7 +541,10 @@ def dedupe_pairs_distributed(
     size is ceil(total/n_shards) * route_slack words (n_rounds *
     n_shards * cap with cap = ceil(chunk/n_shards * route_slack)).
     Routing overflow beyond ``route_slack`` is detected per round and
-    falls back to the single-device driver rather than dropping pairs.
+    falls back to the single-device driver rather than dropping pairs;
+    so does a layout outside the routed engine's contract. Either way
+    the set's ``fallback`` says why; ``exchanges`` holds each round's
+    counts.
 
     ``sort_backend`` picks the shard-local dedupe sort: ``"auto"`` keeps
     the per-platform winner (per-shard numpy u64 ``np.sort`` on the CPU
@@ -479,14 +580,16 @@ def dedupe_pairs_distributed(
                 pairs_lib._round_up(max(1, -(-workload // n_shards)), 1024))
     per_round = n_shards * chunk
     reason = _pair_contract_reason(blocks, budget, per_round, exact)
-    if total == 0 or reason is not None:
-        if reason is not None:
-            warnings.warn(f"routed distributed pairs unavailable ({reason}); "
-                          "using single-device driver", RuntimeWarning,
-                          stacklevel=2)
+    if total == 0:
         return pairs_lib.dedupe_pairs(blocks, budget=budget,
                                       sample_seed=sample_seed,
                                       sort_backend=sort_backend)
+    if reason is not None:
+        warnings.warn(f"routed distributed pairs unavailable ({reason}); "
+                      "using single-device driver", RuntimeWarning,
+                      stacklevel=2)
+        return _single_device_pairs(blocks, budget, sample_seed,
+                                    sort_backend, reason, "contract")
 
     # host casts + explicit uploads: dtype-coercing jnp.asarray and scalar
     # jnp dtype constructors are implicit host->device transfers, rejected
@@ -495,20 +598,23 @@ def dedupe_pairs_distributed(
     size32 = jnp.asarray(blocks.size.astype(np.int32))
     mem32 = jnp.asarray(blocks.members.astype(np.int32))
     steps = pairs_kernels.search_steps_for(int(blocks.size.max()))
-    cap = int(np.ceil(chunk / n_shards * route_slack))
+    cap = _route_cap(chunk, n_shards, route_slack)
     step = _make_routed_round_step(mesh, axes, n_shards, chunk, cap,
                                    steps, sampled=not exact)
 
-    rhi, rlo, ovfs = [], [], []
+    rhi, rlo, ovfs, sents = [], [], [], []
     if exact:
         cum32 = jnp.asarray(
             pairs_ref.cum_pair_counts(blocks.size).astype(np.int32))
         total32 = jax.device_put(np.int32(total))
         shard_offsets = np.arange(n_shards, dtype=np.int32) * chunk
-        for r0 in range(0, total, per_round):
-            base = jnp.asarray(np.int32(r0) + shard_offsets)
-            bhi, blo, ovf = step(cum32, start32, size32, mem32, base, total32)
+        for r, r0 in enumerate(range(0, total, per_round)):
+            with obs.span("repro.dist.pairs.round", round=r):
+                base = jnp.asarray(np.int32(r0) + shard_offsets)
+                bhi, blo, ovf, sent = step(cum32, start32, size32, mem32,
+                                           base, total32)
             rhi.append(bhi); rlo.append(blo); ovfs.append(ovf)
+            sents.append(sent)
     else:
         # budget-exceeded: decode the sample drawn above, split
         # block/local host-side because global slot indices are int64
@@ -521,24 +627,35 @@ def dedupe_pairs_distributed(
             block = np.pad(block, (0, pad))
             local = np.pad(local, (0, pad))
             valid = np.pad(valid, (0, pad))
-        for off in range(0, len(block), per_round):
+        for r, off in enumerate(range(0, len(block), per_round)):
             sl = slice(off, off + per_round)
-            bhi, blo, ovf = step(start32, size32, mem32,
-                                 jnp.asarray(block[sl].reshape(n_shards, chunk)),
-                                 jnp.asarray(local[sl].reshape(n_shards, chunk)),
-                                 jnp.asarray(valid[sl].reshape(n_shards, chunk)))
+            with obs.span("repro.dist.pairs.round", round=r):
+                bhi, blo, ovf, sent = step(
+                    start32, size32, mem32,
+                    jnp.asarray(block[sl].reshape(n_shards, chunk)),
+                    jnp.asarray(local[sl].reshape(n_shards, chunk)),
+                    jnp.asarray(valid[sl].reshape(n_shards, chunk)))
             rhi.append(bhi); rlo.append(blo); ovfs.append(ovf)
+            sents.append(sent)
     # one deferred host sync: rounds pipeline freely in the common
     # no-overflow case, and the fallback discards the buckets anyway
+    ovfs, sents = jax.device_get((ovfs, sents))
+    exchanges = [Exchange("pairs", r, np.asarray(sent), cap)
+                 for r, sent in enumerate(sents)]
+    for ex, ovf in zip(exchanges, ovfs):
+        obs.mark("repro.dist.pairs.round.counts", round=ex.index,
+                 words=int(ex.sent.sum()), overflow=int(ovf),
+                 **ex.span_args())
     if any(int(o) for o in ovfs):
+        reason = (f"routed pair dedupe overflowed a bucket (cap {cap}, "
+                  f"slack {route_slack})")
         warnings.warn(
-            f"routed pair dedupe overflowed a bucket (cap {cap}, slack "
-            f"{route_slack}); falling back to the single-device driver — "
+            f"{reason}; falling back to the single-device driver — "
             "raise route_slack to keep the routed path",
             RepCapacityWarning, stacklevel=2)
-        return pairs_lib.dedupe_pairs(blocks, budget=budget,
-                                      sample_seed=sample_seed,
-                                      sort_backend=sort_backend)
+        return _single_device_pairs(blocks, budget, sample_seed,
+                                    sort_backend, reason, "overflow",
+                                    exchanges)
 
     # routed pairs always satisfy the pack bound (contract check above),
     # so "auto" resolves to the per-platform winner and "radix" never
@@ -572,7 +689,7 @@ def dedupe_pairs_distributed(
     # restores the canonical global (a, b) order
     a, b, s = pairs_kernels.unpack_words_host(np.sort(words))
     return pairs_lib.PairSet(a=a, b=b, src_size=s, exact=exact,
-                             total_slots=total)
+                             total_slots=total, exchanges=exchanges)
 
 
 def materialize_pairs_distributed(

@@ -104,6 +104,9 @@ class BlockingResult:
     key_lo: np.ndarray      # (M,) uint32
     stats: List[IterationStats]
     num_records: int
+    # a sharded run's exact-count exchanges, one per iteration
+    # (``core.distributed.Exchange``); empty on one device
+    exchanges: list = dataclasses.field(default_factory=list)
 
     @property
     def rep_overflow_total(self) -> int:

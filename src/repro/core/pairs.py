@@ -181,6 +181,11 @@ class PairSet:
     # lets the matcher consume the pair buffer without a host round trip
     device_a: Optional[jax.Array] = None
     device_b: Optional[jax.Array] = None
+    # routed distributed dedupe only: its rounds' exchanges
+    # (``core.distributed.Exchange``), and why the single-device engine
+    # made this set instead, where it did
+    exchanges: list = dataclasses.field(default_factory=list)
+    fallback: Optional[str] = None
 
     def pair_buffers(self):
         """(a, b) as device arrays; zero-copy when the device engine

@@ -106,7 +106,7 @@ def _survivors_device(label: jnp.ndarray, n_real: jnp.ndarray, *,
     return surv, jnp.sum(ri)
 
 
-def _pow2_cap(n: int) -> int:
+def pow2_cap(n: int) -> int:
     cap = _MIN_CAP
     while cap < n:
         cap *= 2
@@ -126,7 +126,7 @@ def cluster_pairs_device(num_nodes: int, a: jnp.ndarray, b: jnp.ndarray, *,
     ``label``/``survivors`` are capacity-length (crop host-side with
     ``num_nodes`` / ``n_survivors``).
     """
-    cap = _pow2_cap(num_nodes)
+    cap = pow2_cap(num_nodes)
     label, converged, rounds = _cc_device(a, b, num_nodes=cap,
                                           max_rounds=max_rounds)
     surv, n_surv = _survivors_device(
@@ -163,7 +163,7 @@ def cluster_edges(num_nodes: int, a: np.ndarray, b: np.ndarray, *,
         label = np.arange(num_nodes, dtype=np.int64)
         return ClusterResult(label=label, survivors=label.copy(),
                              converged=True, rounds=0)
-    cap_e = _pow2_cap(m)
+    cap_e = pow2_cap(m)
     ae = np.zeros(cap_e, np.int32)
     be = np.zeros(cap_e, np.int32)
     ae[:m] = np.asarray(a, np.int32)

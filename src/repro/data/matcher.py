@@ -16,6 +16,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.blocks import TokenColumn
 from ..kernels.match import ops as match_ops
@@ -163,3 +164,64 @@ def match_compact(columns: Dict[str, TokenColumn], a, b,
     return match_ops.fused_match_pairs(
         tokens, masks, weights, a, b, threshold=cfg.threshold,
         n_real=n_real, chunk=chunk, use_kernel=(resolved == "pallas"))
+
+
+def match_compact_sharded(columns: Dict[str, TokenColumn], a: np.ndarray,
+                          b: np.ndarray, cfg: MatcherConfig, mesh, *,
+                          backend: str = "auto",
+                          chunk: int = match_ops.DEFAULT_CHUNK):
+    """``match_compact`` over every device of ``mesh``: each matches an
+    equal slice of the host candidate pairs ``a``/``b`` against its own
+    replica of the columns (replicated here). Returns device ``(ca, cb,
+    counts)``: ``ca``/``cb`` split over the devices, each device's slice
+    laid out as ``match_compact``'s output (its matched pairs first, in
+    candidate order, then (0, 0) padding), and ``counts`` each device's
+    matched count. One program for the mesh, whatever its size."""
+    resolved = resolve_match_backend(backend)
+    if resolved == "host":
+        raise ValueError("match_compact_sharded is the device path")
+    tokens, masks, weights = _schema(columns, cfg)
+    n_dev = mesh.devices.size
+    per = -(-len(a) // n_dev)
+    chunk = max(match_ops.CHUNK_QUANTUM,
+                min(chunk, match_ops.round_up(max(per, 1),
+                                              match_ops.CHUNK_QUANTUM)))
+    per = match_ops.round_up(max(per, 1), chunk)
+    n_real = np.clip(len(a) - per * np.arange(n_dev), 0, per).astype(np.int32)
+    split = NamedSharding(mesh, P(mesh.axis_names))
+    everywhere = NamedSharding(mesh, P())
+
+    def lanes(x):
+        out = np.zeros((n_dev * per,), np.int32)
+        out[:len(x)] = x
+        return jax.device_put(out, split)
+
+    step = _sharded_match(mesh, per, chunk, weights, float(cfg.threshold),
+                          resolved == "pallas")
+    return step(jax.device_put(tokens, everywhere),
+                jax.device_put(masks, everywhere), lanes(a), lanes(b),
+                jax.device_put(n_real, split))
+
+
+@functools.lru_cache(maxsize=16)
+def _sharded_match(mesh, per: int, chunk: int, weights: tuple,
+                   threshold: float, use_kernel: bool):
+    """The shard-mapped fused match of ``match_compact_sharded``: the
+    chunks of a device's ``per`` lanes in one ``lax.map``, then one
+    compaction."""
+    axes = mesh.axis_names
+
+    def local(tokens, masks, a, b, n_real):
+        def one(base):
+            return match_ops._match_chunk(
+                tokens, masks, a, b, base, n_real[0], chunk=chunk,
+                weights=weights, threshold=threshold, use_kernel=use_kernel)
+
+        parts = jax.lax.map(one, jnp.arange(0, per, chunk, dtype=jnp.int32))
+        ca, cb, count = match_ops.compact_matched(
+            *(x.reshape(-1) for x in parts))
+        return ca, cb, count[None]
+
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P(), P(), P(axes), P(axes), P(axes)),
+        out_specs=(P(axes), P(axes), P(axes)), check_vma=False))

@@ -4,8 +4,14 @@
     -> graph partition -> canonical records -> token stream -> batches
 
 ``dedup_corpus`` runs stages 2-4 batch-mode and returns one surviving
-record per entity-component. ``DedupPipeline`` is the streaming-consistent
-form: it holds a persistent ``streaming.BlockStore`` so ``extend(delta)``
+record per entity-component. Where the corpus' columns are placed over a
+mesh of several devices (``jax.Array``s whose ``NamedSharding`` splits
+the record axis), the job runs sharded: keys stay on their records'
+devices, HDB and the pair dedupe are ``core.distributed``'s routed
+dataflow, every device matches a slice of the candidate pairs, and one
+device clusters; the answer is the one-device job's (docs/PIPELINE.md,
+"The sharded job"). ``DedupPipeline`` is the streaming-consistent form:
+it holds a persistent ``streaming.BlockStore`` so ``extend(delta)``
 absorbs new records incrementally — blocking work proportional to the
 delta, matching only the new candidate pairs (scored from the device pair
 buffer), retraction-aware — and exposes the current survivors for the
@@ -21,16 +27,20 @@ paths are bit-identical (docs/PIPELINE.md).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import blocks as blocks_mod
+from ..core import distributed
 from ..core import hdb as hdb_mod
 from ..core import pairs as pairs_mod
+from ..distributed import sharding as sharding_lib
 from .. import obs
 from . import components, matcher
 from .synthetic import Corpus
@@ -62,6 +72,30 @@ class DedupReport:
     # for callers that check them against a reference
     blocks: Optional[pairs_mod.Blocks] = None
     pairs: Optional[pairs_mod.PairSet] = None
+    # the devices the records were sharded over (1: the one-device job);
+    # HDB entries dropped for a fixed buffer's capacity (on one device
+    # only the representative buffer can overflow); routed pair dedupes
+    # that fell back to the single-device engine; and the sharded job's
+    # exchanges (``core.distributed.Exchange``), HDB's then the pairs'
+    shards: int = 1
+    route_overflows: int = 0
+    pair_fallbacks: int = 0
+    exchanges: list = dataclasses.field(default_factory=list)
+
+
+def record_mesh(columns: Dict[str, blocks_mod.TokenColumn]):
+    """``(mesh, record axes)`` where the columns' records are split over
+    several devices of a mesh, else None (the one-device job)."""
+    sharding = getattr(next(iter(columns.values())).tokens, "sharding", None)
+    if not isinstance(sharding, NamedSharding) or not sharding.spec:
+        return None
+    axes = sharding.spec[0]
+    if axes is None:
+        return None
+    axes = (axes,) if isinstance(axes, str) else tuple(axes)
+    if sharding_lib.axis_size(sharding.mesh, axes) < 2:
+        return None
+    return sharding.mesh, axes
 
 
 def dedup_corpus(corpus: Corpus,
@@ -72,6 +106,10 @@ def dedup_corpus(corpus: Corpus,
                  verbose: bool = False,
                  match_backend: str = "auto",
                  cc_max_rounds: int = 64) -> DedupReport:
+    placed = record_mesh(corpus.columns)
+    if placed is not None:
+        return _dedup_sharded(corpus, *placed, cfg, match_cfg, pair_budget,
+                              blocker, verbose, match_backend, cc_max_rounds)
     n = corpus.num_records
     with obs.span("repro.pipeline.blocking") as blocking:
         with obs.span("repro.pipeline.keys"):
@@ -107,7 +145,106 @@ def dedup_corpus(corpus: Corpus,
         component_of=label,
         blocks=blk,
         pairs=pset,
+        route_overflows=result.rep_overflow_total,
     )
+
+
+def _dedup_sharded(corpus: Corpus, mesh, axes, cfg, match_cfg, pair_budget,
+                   blocker, verbose, match_backend, cc_max_rounds
+                   ) -> DedupReport:
+    """``dedup_corpus`` over columns whose records are split over
+    ``mesh``'s ``axes``: the same answer, from the routed dataflow."""
+    if blocker != "hdb":
+        raise ValueError(f"the sharded job blocks with HDB only, not "
+                         f"{blocker!r}")
+    backend = matcher.resolve_match_backend(match_backend)
+    if backend == "host":
+        raise ValueError("the sharded job matches on its devices; "
+                         "match_backend='host' is the one-device baseline")
+    n = corpus.num_records
+    with obs.span("repro.pipeline.blocking") as blocking:
+        with obs.span("repro.pipeline.keys"):
+            keys, valid = blocks_mod.build_keys(corpus.columns,
+                                                corpus.blocking)
+        result = distributed.distributed_hashed_dynamic_blocking(
+            keys, valid, cfg, mesh, axes, verbose=verbose)
+        with obs.span("repro.pairs.blocks"):
+            blk = pairs_mod.build_blocks(result)
+        pset = distributed.dedupe_pairs_distributed(blk, mesh, axes,
+                                                    budget=pair_budget)
+    with obs.span("repro.pipeline.match") as matching:
+        with obs.span("repro.dist.match"):
+            ca, cb, counts = matcher.match_compact_sharded(
+                corpus.columns, pset.a, pset.b, match_cfg, mesh,
+                backend=backend)
+            _sync(ca, cb, counts)
+    with obs.span("repro.pipeline.partition") as partition:
+        with obs.span("repro.dist.gather"):
+            ca, cb, num_matched = _gather_matched(mesh, ca, cb, counts,
+                                                  mesh.devices.flat[0])
+        label, survivors = _cluster(n, ca, cb, cc_max_rounds)
+    return DedupReport(
+        num_records=n,
+        num_candidate_pairs=len(pset.a),
+        num_matched_pairs=num_matched,
+        num_components=len(survivors),
+        num_survivors=len(survivors),
+        blocking_seconds=blocking.seconds,
+        matching_seconds=matching.seconds,
+        partition_seconds=partition.seconds,
+        survivors=survivors,
+        component_of=label,
+        blocks=blk,
+        pairs=pset,
+        shards=sharding_lib.axis_size(mesh, axes),
+        route_overflows=result.rep_overflow_total,
+        pair_fallbacks=int(pset.fallback is not None),
+        exchanges=result.exchanges + pset.exchanges,
+    )
+
+
+@functools.lru_cache(maxsize=16)
+def _heads(mesh, size: int):
+    """Shard-mapped: the first ``size`` lanes of each device's slice,
+    zero-padded past its end."""
+    axes = mesh.axis_names
+
+    def local(ca, cb):
+        m = min(size, ca.shape[0])
+        return tuple(jnp.zeros((size,), x.dtype).at[:m].set(x[:m])
+                     for x in (ca, cb))
+
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(axes),) * 2,
+                                 out_specs=(P(axes),) * 2, check_vma=False))
+
+
+def _gather_matched(mesh, ca, cb, counts, device):
+    """The devices' matched pairs on ``device``, as one buffer
+    ``cluster_pairs_device`` reads: each device's compacted head, cut to
+    a power-of-two bucket over the largest count. Zero lanes are (0, 0)
+    self-edges, which clustering ignores. Returns ``(ca, cb, matched)``."""
+    counts = np.asarray(counts)
+    ha, hb = _heads(mesh, components.pow2_cap(int(counts.max())))(ca, cb)
+    return (jax.device_put(ha, device), jax.device_put(hb, device),
+            int(counts.sum()))
+
+
+def _cluster(n: int, ca, cb, cc_max_rounds: int):
+    """Connected components of a device matched-pair buffer: host
+    ``(label, survivors)``, under the ``repro.components.cc`` span."""
+    with obs.span("repro.components.cc"):
+        label_d, surv_d, n_surv, converged, rounds = \
+            components.cluster_pairs_device(n, ca, cb,
+                                            max_rounds=cc_max_rounds)
+        _sync(label_d, surv_d)
+    converged, rounds = jax.device_get((converged, rounds))
+    obs.mark("repro.components.cc.counts", rounds=int(rounds),
+             converged=bool(converged))
+    if not converged:
+        components._warn_truncated(cc_max_rounds)
+    label = np.asarray(label_d)[:n].astype(np.int64)
+    survivors = np.asarray(surv_d)[:int(np.asarray(n_surv))].astype(np.int64)
+    return label, survivors
 
 
 def match_and_cluster(corpus: Corpus, pset: pairs_mod.PairSet,
@@ -146,20 +283,8 @@ def match_and_cluster(corpus: Corpus, pset: pairs_mod.PairSet,
             # canonical survivor = min record id per component == the label
             survivors = np.unique(label)
         else:
-            with obs.span("repro.components.cc"):
-                label_d, surv_d, n_surv, converged, rounds = \
-                    components.cluster_pairs_device(
-                        n, ca, cb, max_rounds=cc_max_rounds)
-                _sync(label_d, surv_d)
-            converged, rounds = jax.device_get((converged, rounds))
-            obs.mark("repro.components.cc.counts", rounds=int(rounds),
-                     converged=bool(converged))
-            if not converged:
-                components._warn_truncated(cc_max_rounds)
+            label, survivors = _cluster(n, ca, cb, cc_max_rounds)
             num_matched = int(np.asarray(cnt))
-            label = np.asarray(label_d)[:n].astype(np.int64)
-            survivors = np.asarray(surv_d)[:int(np.asarray(n_surv))].astype(
-                np.int64)
     return (num_matched, label, survivors, matching.seconds,
             partition.seconds)
 

@@ -34,7 +34,7 @@ from .match import SUBLANES, _LANES, match_score_pallas
 
 # chunk granularity: multiple of lanes, amortizes dispatch without
 # blowing VMEM on the (C, T, chunk) gathered stacks
-_CHUNK_QUANTUM = 1024
+CHUNK_QUANTUM = 1024
 DEFAULT_CHUNK = 1 << 16
 
 
@@ -75,7 +75,7 @@ def score_lanes_jnp(tokens, masks, weights, a, b) -> jnp.ndarray:
     return jnp.where(norm > 0, total / jnp.maximum(norm, 1e-6), 0.0)
 
 
-def _round_up(x: int, q: int) -> int:
+def round_up(x: int, q: int) -> int:
     return ((x + q - 1) // q) * q
 
 
@@ -97,7 +97,7 @@ def _match_chunk(tokens, masks, a, b, base, n_real, *, chunk: int,
     aa = a[idx]
     bb = b[idx]
     if use_kernel:
-        t_pad = _round_up(max(t.shape[1] for t in tokens), SUBLANES)
+        t_pad = round_up(max(t.shape[1] for t in tokens), SUBLANES)
         # stack columns as (C, T_pad, chunk): pairs ride the lane axis
         def stacked(cols, rows, cast):
             out = []
@@ -163,7 +163,7 @@ def fused_match_pairs(tokens, masks, weights, a, b, *, threshold: float,
         # constant implicitly and trips transfer_guard("disallow")
         z = jax.device_put(np.zeros((0,), np.int32))
         return z, z, jax.device_put(np.int32(0))
-    chunk = max(_CHUNK_QUANTUM, min(chunk, _round_up(n, _CHUNK_QUANTUM)))
+    chunk = max(CHUNK_QUANTUM, min(chunk, round_up(n, CHUNK_QUANTUM)))
     assert chunk % _LANES == 0
     n_dev = jax.device_put(np.int32(n))
     parts = []

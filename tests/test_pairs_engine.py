@@ -386,13 +386,15 @@ def test_routed_int32_guard_at_slot_edge(monkeypatch):
                        np.arange(n, dtype=np.int64))
     total = blk.num_pair_slots
     assert total + (1 << 18) > 2**31 - 1 > total  # sits exactly at the edge
-    sentinel = object()
+    z = np.zeros((0,), np.int64)
+    sentinel = pairs.PairSet(z, z, z, True, 0)
     monkeypatch.setattr(pairs, "dedupe_pairs", lambda *a, **k: sentinel)
     mesh = jax.make_mesh((1,), ("data",))
     with pytest.warns(RuntimeWarning, match="overflows int32"):
         got = dedupe_pairs_distributed(blk, mesh, ("data",),
                                        budget=2**31 - 2)
     assert got is sentinel  # fell back without decoding 2B slots
+    assert "overflows int32" in got.fallback  # and the set says why
 
 
 def test_routed_decode_validity_at_int32_slot_edge_per_shard_bases():
